@@ -157,18 +157,17 @@ def _rebuild_scale(*, smoke: bool) -> None:
     infeasible; the cells that remain are the device rebuild paths the
     tentpole added.
     """
-    import jax
-
     from benchmarks.common import timed
     from repro.core.clustering import pairwise_distances
     from repro.core.samplers.algorithm2 import build_plan_algorithm2
     from repro.core.types import ClientPopulation
+    from repro.kernels import interpret_mode
     from repro.kernels.similarity.ops import (
         make_distance_fn,
         pairwise_distances_streamed,
     )
 
-    interpret = jax.default_backend() != "tpu"
+    interpret = interpret_mode()
     n_small, n_big, d = (48, 200, 16) if smoke else (512, 10_000, 64)
     m_small, m_big = (5, 5) if smoke else (24, 50)
     rng = np.random.default_rng(0)

@@ -51,6 +51,7 @@ from benchmarks import (
     scheme_race,
     table_variance,
 )
+from repro.launch.compile_cache import enable_compile_cache
 
 MODULES = [
     ("table_variance", table_variance),
@@ -166,6 +167,7 @@ def main(argv: "list[str] | None" = None) -> None:
         "clusterers / benchmark modules",
     )
     args = ap.parse_args(sys.argv[1:] if argv is None else argv)
+    enable_compile_cache()
     if args.list:
         list_registered()
         return

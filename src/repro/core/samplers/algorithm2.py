@@ -213,9 +213,9 @@ class Algorithm2Sampler(StoreBackedSampler):
 
         ``distance_fn`` selects the O(n²d) pairwise-distance backend: a
         backend name (``"auto"`` — the default device path: compiled Pallas
-        on TPU, interpret-mode Pallas everywhere else, GPU included — the
-        kernel's VMEM scratch is TPU-only; ``"pallas"`` — TPU only, errors
-        elsewhere; ``"pallas-interpret"``; ``"streamed"`` — d-chunked
+        on TPU, interpret-mode Pallas on CPU, an error on any other
+        platform; ``"pallas"`` — TPU only, errors elsewhere;
+        ``"pallas-interpret"``; ``"streamed"`` — d-chunked
         accumulation for model-sized gradients; ``"numpy"``), a custom
         callable, or ``None`` for the numpy host reference.
 

@@ -520,9 +520,17 @@ def run_sweep(
     uninterrupted run. ``workers > 1`` fans independent cells out over a
     spawn-based process pool (each worker writes its own cell files; the
     parent finalization order doesn't matter because cell files are
-    disjoint). ``on_cell(cell, status, summary, seconds)`` streams progress
-    with ``status`` in ``{"ran", "skipped"}``.
+    disjoint); it needs ``JAX_PLATFORMS=cpu``, since every worker would
+    otherwise claim the accelerator. ``on_cell(cell, status, summary,
+    seconds)`` streams progress with ``status`` in ``{"ran", "skipped"}``.
     """
+    if workers > 1 and os.environ.get("JAX_PLATFORMS") != "cpu":
+        raise RuntimeError(
+            f"run_sweep(workers={workers}) would start {workers} processes "
+            "that each open JAX's default device, and an accelerator belongs "
+            "to one process at a time; run with workers=1, or pin every "
+            "process to the CPU with JAX_PLATFORMS=cpu"
+        )
     sweep = SweepSpec.from_dict(sweep) if isinstance(sweep, dict) else sweep
     store = RunStore(store_dir)
     store.write_manifest(sweep)

@@ -2,7 +2,8 @@
 
 Drop-in replacement for ``repro.core.clustering.similarity.pairwise_distances``
 (numpy) — Algorithm 2 passes ``distance_fn=pallas_pairwise_distances`` to run
-the O(n²d) stage on TPU. On CPU builds, set ``interpret=True`` (tests do).
+the O(n²d) stage on TPU. On CPU the kernels run with ``interpret=True``
+(:func:`repro.kernels.interpret_mode` decides for the resolved backends).
 
 Three entry points:
 
@@ -24,6 +25,7 @@ from __future__ import annotations
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels import call_on_mesh, interpret_mode
 from repro.kernels.similarity.kernel import pairwise_kernel, pairwise_kernel_fused
 from repro.kernels.similarity.ref import distances_from_gram
 
@@ -150,15 +152,12 @@ def make_distance_fn(
 
     def fn(G, measure: str):
         if chunked:
-            out = pairwise_distances_chunked(
-                G, measure, d_chunk=d_chunk, interpret=interpret
-            )
+            kernel, kw = pairwise_distances_chunked, {"d_chunk": d_chunk}
         elif streamed or G.shape[1] > d_chunk:
-            out = pairwise_distances_streamed(
-                G, measure, d_chunk=d_chunk, interpret=interpret
-            )
+            kernel, kw = pairwise_distances_streamed, {"d_chunk": d_chunk}
         else:
-            out = pairwise_distances_device(G, measure, interpret=interpret)
+            kernel, kw = pairwise_distances_device, {}
+        out = call_on_mesh(kernel, G, measure=measure, interpret=interpret, **kw)
         return np.asarray(out) if as_numpy else out
 
     return fn
@@ -167,11 +166,12 @@ def make_distance_fn(
 def resolve_distance_backend(backend: str = "auto", *, as_numpy: bool = True):
     """Pick the pairwise-distance backend for Algorithm 2's O(n²d) stage.
 
-    * ``"auto"``     — compiled Pallas kernel on TPU, interpret-mode Pallas
-      everywhere else — including GPU (same code path, jax-ops execution;
-      the kernel's ``pltpu.VMEM`` scratch / mosaic block specs are
-      TPU-only, so there is no compiled GPU path). Switches to the fused
-      streamed kernel once ``d`` exceeds :data:`STREAM_D_THRESHOLD`.
+    The device backends compile their Pallas kernels on TPU and run them in
+    interpret mode on CPU (:func:`repro.kernels.interpret_mode`); any other
+    platform is an error.
+
+    * ``"auto"``     — the device kernel; switches to the fused streamed
+      kernel once ``d`` exceeds :data:`STREAM_D_THRESHOLD`.
     * ``"pallas"``   — compiled Pallas kernel; TPU only, errors elsewhere.
     * ``"pallas-interpret"`` — interpret-mode Pallas anywhere (tests).
     * ``"streamed"`` — always the fused streamed kernel (one launch, d-grid
@@ -189,32 +189,22 @@ def resolve_distance_backend(backend: str = "auto", *, as_numpy: bool = True):
 
         return pairwise_distances
     if backend == "auto":
-        import jax
-
-        return make_distance_fn(
-            interpret=jax.default_backend() != "tpu", as_numpy=as_numpy
-        )
+        return make_distance_fn(interpret=interpret_mode(), as_numpy=as_numpy)
     if backend == "streamed":
-        import jax
-
         return make_distance_fn(
-            interpret=jax.default_backend() != "tpu", streamed=True, as_numpy=as_numpy
+            interpret=interpret_mode(), streamed=True, as_numpy=as_numpy
         )
     if backend == "chunked":
-        import jax
-
         return make_distance_fn(
-            interpret=jax.default_backend() != "tpu", chunked=True, as_numpy=as_numpy
+            interpret=interpret_mode(), chunked=True, as_numpy=as_numpy
         )
     if backend == "pallas":
-        import jax
-
-        if jax.default_backend() != "tpu":
+        if interpret_mode():
             raise RuntimeError(
-                "distance backend 'pallas' requires a TPU — the kernel's "
-                "pltpu.VMEM scratch and mosaic block specs do not lower on "
-                f"{jax.default_backend()!r}; use 'auto' (interpret-mode "
-                "fallback) or 'pallas-interpret' instead"
+                "distance backend 'pallas' requires a TPU: it compiles its "
+                "kernel with Mosaic, and JAX's default platform is 'cpu', "
+                "where Pallas kernels run only in interpret mode; use 'auto' "
+                "or 'pallas-interpret'"
             )
         return make_distance_fn(interpret=False, as_numpy=as_numpy)
     if backend == "pallas-interpret":

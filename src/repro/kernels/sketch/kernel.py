@@ -17,8 +17,9 @@ the last d-step. Structure mirrors
 * VMEM footprint per step: bn·bd X tile + bd·d_prime sign tile + bn·d_prime
   accumulator — ~(128·512 + 512·64 + 128·64)·4 B ≈ 420 KiB at defaults.
 
-``interpret=True`` runs the identical program as jax ops on CPU/GPU
-(the same convention as the similarity kernels).
+``interpret=True`` runs the identical program as jax ops on CPU (the
+same convention as the similarity kernels; :func:`repro.kernels.
+interpret_mode` picks it from the platform).
 """
 from __future__ import annotations
 

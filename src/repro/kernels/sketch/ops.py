@@ -118,14 +118,16 @@ class SRPSketcher(Sketcher):
             return self.reference(X)
         import jax.numpy as jnp
 
+        from repro.kernels import call_on_mesh, interpret_mode
         from repro.kernels.sketch.kernel import srp_sketch_kernel
 
-        return srp_sketch_kernel(
+        return call_on_mesh(
+            srp_sketch_kernel,
             jnp.asarray(X),
             d_prime=self.d_out,
             seed=self.seed,
             block_d=self.block_d,
-            interpret=jax.default_backend() != "tpu",
+            interpret=interpret_mode(),
         )
 
     def reference(self, X) -> np.ndarray:

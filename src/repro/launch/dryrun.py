@@ -160,16 +160,8 @@ def _compile(cfg, shape, mesh, *, expert_parallel: bool):
     return compiled, kind, state_shape
 
 
-def normalize_cost_analysis(cost):
-    """``Compiled.cost_analysis()`` drifted from per-device [dict] to dict
-    across jax versions — normalize to the dict (shared with dryrun_fl)."""
-    if isinstance(cost, (list, tuple)):
-        return cost[0] if cost else {}
-    return cost
-
-
 def _costs(compiled):
-    cost = normalize_cost_analysis(compiled.cost_analysis())
+    cost = compiled.cost_analysis()
     colls = rl.parse_collectives(compiled.as_text())
     return {
         "flops": float(cost.get("flops", 0.0)),

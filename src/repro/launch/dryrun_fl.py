@@ -96,10 +96,8 @@ def run_fl_round(
         )
         compiled = jitted.lower(abstract_params(cfg), specs).compile()
 
-    from repro.launch.dryrun import normalize_cost_analysis
-
     mem = compiled.memory_analysis()
-    cost = normalize_cost_analysis(compiled.cost_analysis())
+    cost = compiled.cost_analysis()
     colls = rl.parse_collectives(compiled.as_text())
     # NOTE: the model body runs under vmap+scan(local steps) — while-loop
     # body counted once, so per-LOCAL-STEP cost ≈ reported cost directly;

@@ -61,6 +61,9 @@ def main(argv=None) -> int:
         ap.error(f"--status-every must be >= 1, got {args.status_every}")
 
     from repro.fl.experiment import ExperimentSpec, load_spec_dict
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     spec = ExperimentSpec.from_dict(load_spec_dict(args.spec))
     if spec.train.checkpoint_every <= 0:

@@ -11,36 +11,22 @@ inside a pod (ICI).
 """
 from __future__ import annotations
 
-import inspect
-
 import jax
+from jax.sharding import AxisType
 
-# ``AxisType`` (and ``jax.make_mesh(..., axis_types=...)``) only exist on
-# newer jax. Auto axes are also the default there, so on older jax we simply
-# omit the kwarg — semantics are identical.
-try:
-    from jax.sharding import AxisType  # type: ignore[attr-defined]
-except ImportError:  # pragma: no cover - depends on installed jax
-    AxisType = None
-
-
-def _axis_types_kwargs(n_axes: int) -> dict:
-    if AxisType is None:
-        return {}
-    if "axis_types" not in inspect.signature(jax.make_mesh).parameters:
-        return {}
-    return {"axis_types": (AxisType.Auto,) * n_axes}
+# ``jax.make_mesh`` defaults to Explicit axes; the sharding constraints of the
+# engine, the gradient store and the model hints need Auto ones.
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_types_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
     """Tiny mesh over the locally available devices (tests)."""
-    return jax.make_mesh((data, model), ("data", "model"), **_axis_types_kwargs(2))
+    return jax.make_mesh((data, model), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
 
 
 def resolve_fl_mesh(spec):

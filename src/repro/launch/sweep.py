@@ -15,6 +15,7 @@ import argparse
 import sys
 
 from repro.fl.sweep import SweepSpec, cell_group_label, run_sweep, write_collated
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main(argv: "list[str] | None" = None) -> None:
@@ -26,6 +27,7 @@ def main(argv: "list[str] | None" = None) -> None:
     ap.add_argument("--no-collate", action="store_true", help="skip writing cells.csv / summary.csv")
     ap.add_argument("--list-cells", action="store_true", help="print the expanded grid and exit")
     args = ap.parse_args(sys.argv[1:] if argv is None else argv)
+    enable_compile_cache()
 
     sweep = SweepSpec.from_arg(args.sweep)
     cells = sweep.cells()

@@ -64,12 +64,9 @@ def constrain(x, *dims):
 
 
 def _active_mesh_shape():
-    env = jax.sharding.get_abstract_mesh() if hasattr(jax.sharding, "get_abstract_mesh") else None
-    try:
-        if env is not None and env.axis_names:
-            return dict(zip(env.axis_names, env.axis_sizes))
-    except Exception:  # noqa: BLE001
-        pass
+    env = jax.sharding.get_abstract_mesh()
+    if env.axis_names:
+        return dict(zip(env.axis_names, env.axis_sizes))
     # fall back to the physical mesh context
     try:
         from jax.interpreters.pxla import thread_resources

@@ -5,5 +5,3 @@ import sys
 # count (1 CPU device) — the 512-device XLA flag is set ONLY inside
 # repro.launch.dryrun / subprocess-based sharding tests.
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
-# make tests/ importable as a flat namespace (for _hypothesis_compat)
-sys.path.insert(0, os.path.dirname(__file__))

@@ -1,7 +1,7 @@
 """Urn-filling allocator invariants (Appendix C)."""
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.allocation import allocate_by_groups, allocate_by_size, fill_urns_sequential
 

@@ -54,3 +54,28 @@ def test_example_quickstart_runs():
     out = _run(["examples/sampling_statistics.py", "--sizes", "50", "50", "100", "--m", "2"])
     assert out.returncode == 0, out.stderr[-2000:]
     assert "Var ratio" in out.stdout
+
+
+def test_compile_cache_honours_the_environment(monkeypatch):
+    import jax
+
+    from repro.launch.compile_cache import enable_compile_cache
+
+    calls = []
+    monkeypatch.setattr(jax.config, "update", lambda *a: calls.append(a))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+    assert enable_compile_cache() == "/elsewhere/cache"
+    assert calls == []  # JAX reads the variable itself; no other dir is set
+
+
+def test_compile_cache_defaults_to_one_checkout_path(monkeypatch):
+    import jax
+
+    from repro.launch.compile_cache import enable_compile_cache
+
+    calls = []
+    monkeypatch.setattr(jax.config, "update", lambda *a: calls.append(a))
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    first, second = enable_compile_cache(), enable_compile_cache()
+    assert first == second == os.path.join(os.path.realpath(ROOT), ".jax_cache")
+    assert calls == [("jax_compilation_cache_dir", first)] * 2

@@ -238,7 +238,22 @@ shard2.load(G)
 odd = GradientStore(n + 1, d, sketch="srp", sketch_dim=dp, mesh_spec="4x1")
 odd.update(np.array([0]), np.ones((1, d), np.float32))
 
+# the Pallas kernels on mesh-sharded inputs (a Mosaic kernel cannot be
+# partitioned; call_on_mesh runs it replicated over the input's mesh)
+from repro.core.clustering import pairwise_distances
+from repro.kernels.similarity.ops import resolve_distance_backend
+
+dist = resolve_distance_backend("auto", as_numpy=False)(G, "arccos")
+X = jax.device_put(rng.normal(size=(n, d)).astype(np.float32), G.sharding)
+sk = shard.sketch(X)
+
 print(json.dumps({
+    "mesh_kernels_match": bool(
+        np.allclose(np.asarray(dist), pairwise_distances(np.asarray(G), "arccos"), atol=1e-5)
+        and np.allclose(np.asarray(sk), shard.sketch.reference(np.asarray(X)), atol=1e-5)
+    ),
+    "mesh_kernels_replicated": [len(dist.sharding.device_set), len(sk.sharding.device_set),
+                                dist.sharding.is_fully_replicated, sk.sharding.is_fully_replicated],
     "devices": jax.device_count(),
     "sharded_matches": bool(np.array_equal(plain.asnumpy(), shard.asnumpy())),
     "n_shards": n_shards,
@@ -260,6 +275,14 @@ def sharded_store_results():
     )
     assert out.returncode == 0, f"sharded-store subprocess failed:\n{out.stderr[-3000:]}"
     return json.loads(out.stdout.strip().splitlines()[-1])
+
+
+def test_kernels_run_on_mesh_sharded_inputs(sharded_store_results):
+    """Distances over the sharded store and the SRP sketch of a sharded
+    block match their references and come back replicated on the mesh."""
+    r = sharded_store_results
+    assert r["mesh_kernels_match"]
+    assert r["mesh_kernels_replicated"] == [4, 4, True, True]
 
 
 def test_sharded_store_matches_unsharded(sharded_store_results):

@@ -198,6 +198,23 @@ def test_pallas_backend_requires_tpu():
         resolve_distance_backend("pallas")
 
 
+def test_interpret_mode_follows_the_platform(monkeypatch):
+    """CPU interprets, TPU compiles, and any other platform is an error
+    rather than a silent interpreter run."""
+    from repro.kernels import interpret_mode
+
+    assert interpret_mode() is True
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert interpret_mode() is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="'gpu'"):
+        interpret_mode()
+    from repro.kernels.similarity.ops import resolve_distance_backend
+
+    with pytest.raises(RuntimeError, match="'gpu'"):
+        resolve_distance_backend("auto")
+
+
 # --------------------------------------------------------------------------
 # aggregate
 # --------------------------------------------------------------------------

@@ -27,7 +27,7 @@ from repro.models.config import InputShape
 from repro.models.sharding_hints import sharding_hints
 
 results = {}
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_host_mesh(2, 4)
 for arch, kind in [("qwen3-0.6b", "train"), ("xlstm-125m", "decode"), ("deepseek-v2-lite-16b", "train")]:
     cfg = get_config(arch, reduced=True)
     cfg = dataclasses.replace(cfg, d_model=256, vocab_size=1024, scan_layers=False)
@@ -39,8 +39,6 @@ for arch, kind in [("qwen3-0.6b", "train"), ("xlstm-125m", "decode"), ("deepseek
         jitted = jax.jit(step_fn, in_shardings=in_sh, out_shardings=out_sh)
         compiled = jitted.lower(state_shape, specs).compile()
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):  # older jax returns one dict per device
-        cost = cost[0] if cost else {}
     colls = rl.parse_collectives(compiled.as_text())
     results[arch] = {
         "flops": cost.get("flops", 0.0),
@@ -71,8 +69,6 @@ step = make_fl_engine_step(image_loss, sgd(0.1), mesh=mesh)
 with mesh:
     compiled = jax.jit(step, in_shardings=(p_repl, sh)).lower(p_abs, specs).compile()
 cost = compiled.cost_analysis()
-if isinstance(cost, (list, tuple)):
-    cost = cost[0] if cost else {}
 colls = rl.parse_collectives(compiled.as_text())
 results["fl_engine"] = {
     "flops": cost.get("flops", 0.0),

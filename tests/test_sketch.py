@@ -19,7 +19,7 @@ Three layers of contract:
 import numpy as np
 import pytest
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.kernels.sketch import SKETCHERS, Sketcher, resolve_sketcher
 from repro.kernels.sketch.ops import (

@@ -7,7 +7,7 @@ Algorithm 1/2:
   * both with equality iff every W_k equals W_0.
 """
 import numpy as np
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import (
     ClientPopulation,

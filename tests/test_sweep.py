@@ -297,7 +297,8 @@ def test_collation_matches_numpy_over_real_runs(tmp_path):
 # --------------------------------------------------------------------------
 # process-pool fan-out ≡ serial, byte for byte
 # --------------------------------------------------------------------------
-def test_parallel_workers_match_serial(tmp_path):
+def test_parallel_workers_match_serial(tmp_path, monkeypatch):
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")  # the pool runs only on the CPU
     sweep = SweepSpec.from_dict(
         {
             "base": {**BASE, "train": {**BASE["train"], "n_rounds": 2, "n_local_steps": 2}},
@@ -308,6 +309,18 @@ def test_parallel_workers_match_serial(tmp_path):
     serial = run_sweep(sweep, tmp_path / "serial", workers=1)
     pooled = run_sweep(sweep, tmp_path / "pooled", workers=2)
     assert _csv_bytes(pooled) == _csv_bytes(serial)
+
+
+@pytest.mark.parametrize("platforms", [None, "tpu", "cpu,tpu"])
+def test_worker_pool_refused_off_the_cpu(tmp_path, monkeypatch, platforms):
+    """Each pool worker would open the accelerator, which serves one process."""
+    if platforms is None:
+        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    else:
+        monkeypatch.setenv("JAX_PLATFORMS", platforms)
+    with pytest.raises(RuntimeError, match="JAX_PLATFORMS=cpu"):
+        run_sweep(_sweep({"sampler.name": ["md", "algorithm1"]}), tmp_path / "s", workers=2)
+    assert not (tmp_path / "s").exists()
 
 
 # --------------------------------------------------------------------------
