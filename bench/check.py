@@ -1,0 +1,124 @@
+"""The numbers that decide ``correct``: the program's first rounds against the reference.
+
+Each number is a reading of one guarantee over the checked rounds:
+
+* ``plan_gap``: the largest gap between an entry of a plan the program drew
+  from and the same entry of the plan the reference built for that round
+  (the reference's plans meet Proposition 1 by construction);
+* ``draw_mismatch``: urn draws in which the program's client differs from
+  the reference's (exact; limit 0);
+* ``loss_rel``: the largest relative gap of a round's train loss;
+* ``update_gap``: the first round's change of the global model, by the worst
+  leaf: ``| |d_prog| - |d_ref| |`` over ``max(|d_ref|, median leaf |d_ref|)``;
+* ``change_gap``: the same for the change over all checked rounds;
+* ``acc_gap``: the largest gap of a round's test accuracy;
+* ``rows_rel``: the largest relative distance of a client's representative
+  gradient (``theta_i - theta``, the engine's per-client output) from the
+  reference's;
+* ``store_rel`` (plans built from the gradient store): the same for the rows
+  of the store after the last checked round;
+* ``dist_abs`` (same): the largest gap, in radians, of an angle the plan
+  rebuild computed.
+
+Leaves whose reference change is under a thousandth of the median leaf's
+are left out of the two gaps of norms: they move by round-off alone. In
+the same way a representative gradient under a thousandth of the median
+one is left out of ``rows_rel``, ``store_rel`` and ``dist_abs``: a client
+that already fits its data moves by round-off, and its direction is noise
+in any precision. Rows that are exactly zero (clients not yet drawn) stay
+in the angles.
+"""
+from __future__ import annotations
+
+import math
+
+import numpy as np
+
+
+def _leaf_gap(prog_delta: dict, ref_delta: dict) -> float:
+    names = sorted(ref_delta)
+    ref = np.array([np.linalg.norm(ref_delta[k]) for k in names])
+    got = np.array([np.linalg.norm(np.asarray(prog_delta[k], np.float64)) for k in names])
+    median = float(np.median(ref))
+    keep = ref >= 1e-3 * median
+    if not keep.any():
+        return math.inf
+    return float((np.abs(got - ref) / np.maximum(ref, median))[keep].max())
+
+
+def _sound(norms: np.ndarray) -> np.ndarray:
+    """Rows at or above a thousandth of the median non-zero row norm."""
+    live = norms[norms > 0]
+    return norms >= 1e-3 * float(np.median(live)) if live.size else norms > 0
+
+
+def _rows_rel(got: np.ndarray, want: np.ndarray) -> float:
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    norms = np.linalg.norm(want, axis=1)
+    keep = _sound(norms)
+    if not keep.any():
+        return math.inf
+    return float((np.linalg.norm(got - want, axis=1)[keep] / norms[keep]).max())
+
+
+def numbers(prog: dict, ref: dict) -> dict:
+    """The compared numbers of one run; ``prog`` and ``ref`` hold the same keys
+    as :func:`reference.replay`'s result."""
+    k = len(ref["loss"])
+    if len(prog["loss"]) != k:
+        return {"rounds_missing": float(k - len(prog["loss"]))}
+    delta = lambda side, a, b: {n: np.asarray(side["params"][b][n], np.float64)
+                                - np.asarray(side["params"][a][n], np.float64)
+                                for n in side["params"][0]}
+    out = {
+        "draw_mismatch": float(sum(int((np.asarray(a) != b).sum())
+                                   for a, b in zip(prog["clients"], ref["clients"]))),
+        "plan_gap": max(float(np.abs(np.asarray(a, np.float64) - b).max())
+                        for a, b in zip(prog["plans"], ref["plans"])),
+        "loss_rel": max(abs(a - b) / abs(b) for a, b in zip(prog["loss"], ref["loss"])),
+        "update_gap": _leaf_gap(delta(prog, 0, 1), delta(ref, 0, 1)),
+        "change_gap": _leaf_gap(delta(prog, 0, k), delta(ref, 0, k)),
+        "acc_gap": max(abs(a - b) for a, b in zip(prog["acc"], ref["acc"])),
+        "rows_rel": max(
+            _rows_rel([got[i] for i in want], list(want.values())) if set(got) == set(want)
+            else math.inf
+            for got, want in zip(prog["rows"], ref["rows"])
+        ),
+    }
+    if ref["store"]:
+        if not prog["store"] or len(prog["dist"]) != len(ref["dist"]):
+            return dict(out, store_rel=math.inf, dist_abs=math.inf)
+        out["store_rel"] = _rows_rel(prog["store"][-1], ref["store"][-1])
+        gaps = []
+        for got, want, norms in zip(prog["dist"], ref["dist"], ref["pool_norms"]):
+            keep = _sound(norms) | (norms == 0)
+            gap = np.abs(np.asarray(got, np.float64) - want)[np.ix_(keep, keep)]
+            gaps.append(float(gap.max()))
+        out["dist_abs"] = max(gaps)
+    return out
+
+
+def verdict(values: dict, limits: dict) -> tuple[bool, dict]:
+    """``correct`` and ``{name: {"value", "limit"}}``. A number without a limit,
+    a limit without its number, or a value that is not finite fails."""
+    table, ok = {}, True
+    for name in sorted(set(values) | set(limits)):
+        value, limit = values.get(name, math.nan), limits.get(name, math.nan)
+        table[name] = {"value": value, "limit": limit}
+        ok = ok and math.isfinite(value) and math.isfinite(limit) and value <= limit
+    return ok, table
+
+
+def program_record(capture, params0: dict) -> dict:
+    """The capture of the program's checked rounds, in :func:`reference.replay`'s shape."""
+    return {
+        "clients": capture.clients,
+        "plans": capture.plans,
+        "params": [params0] + capture.params,
+        "loss": capture.loss,
+        "acc": capture.acc,
+        "rows": capture.rows,
+        "store": [] if capture.store is None else [capture.store],
+        "dist": capture.dist,
+    }
+
