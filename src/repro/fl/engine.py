@@ -29,6 +29,13 @@ from the server's host rng per distinct client, in distinct order, and
 padded slots consume no randomness — so the same seed yields the same
 realized batches on both paths.
 
+Tracing: a round's engine work shows in a profiler trace as three spans
+inside the server's ``fl.local_work`` — ``fl.local_work.prep`` (slot ids,
+batch indices, weights; counters ``distinct`` and ``slots``),
+``fl.local_work.dispatch`` (the step's call and its ``updates[:c]`` slice;
+counter ``bytes``, what the call moves to the device) and
+``fl.local_work.wait`` (the host blocking on the losses).
+
 Mesh sharding (``mesh=`` on the engine / ``batched_round_step``): the round
 is embarrassingly parallel over clients — each data-parallel group plays
 one sampled client (the ``launch.fl_train`` pattern). With a mesh, the
@@ -47,6 +54,7 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.registry import Registry
@@ -221,34 +229,41 @@ class BatchedRoundEngine:
         c = len(distinct)
         if c == 0 or c > self.m_slots:
             raise ValueError(f"got {c} distinct clients for {self.m_slots} slots")
-        slot_ids = np.zeros(self.m_slots, dtype=np.int32)
-        slot_ids[:c] = distinct
-        idx = np.zeros((self.m_slots, self.n_steps, self.batch_size), dtype=np.int32)
-        for i, cid in enumerate(distinct):
-            # same rng stream as the compat loop's draw_batch_indices, drawn
-            # host-side (one device transfer for the whole block below)
-            idx[i] = rng.integers(
-                0, int(self._n_train[int(cid)]), size=(self.n_steps, self.batch_size)
+        with TraceAnnotation("fl.local_work.prep", distinct=c, slots=self.m_slots):
+            slot_ids = np.zeros(self.m_slots, dtype=np.int32)
+            slot_ids[:c] = distinct
+            idx = np.zeros((self.m_slots, self.n_steps, self.batch_size), dtype=np.int32)
+            for i, cid in enumerate(distinct):
+                # same rng stream as the compat loop's draw_batch_indices, drawn
+                # host-side (one device transfer for the whole block below)
+                idx[i] = rng.integers(
+                    0, int(self._n_train[int(cid)]), size=(self.n_steps, self.batch_size)
+                )
+            w = np.zeros(self.m_slots, dtype=np.float32)
+            w[:c] = weights
+            # the four 32-bit inputs the dispatch moves to the device
+            h2d = slot_ids.nbytes + idx.nbytes + w.nbytes + np.dtype(np.float32).itemsize
+        with TraceAnnotation("fl.local_work.dispatch", bytes=h2d):
+            new_params, updates, losses = batched_round_step(
+                params,
+                self._x_all,
+                self._y_all,
+                jnp.asarray(slot_ids),
+                jnp.asarray(idx),
+                jnp.asarray(w),
+                jnp.asarray(stale_weight, jnp.float32),
+                loss_fn=loss_fn,
+                opt=opt,
+                fedprox_mu=fedprox_mu,
+                mesh=self.mesh,
             )
-        w = np.zeros(self.m_slots, dtype=np.float32)
-        w[:c] = weights
-        new_params, updates, losses = batched_round_step(
-            params,
-            self._x_all,
-            self._y_all,
-            jnp.asarray(slot_ids),
-            jnp.asarray(idx),
-            jnp.asarray(w),
-            jnp.asarray(stale_weight, jnp.float32),
-            loss_fn=loss_fn,
-            opt=opt,
-            fedprox_mu=fedprox_mu,
-            mesh=self.mesh,
-        )
-        # updates stay a device array: the gradient store scatters them back
-        # into G without a host round-trip (the (m_slots, d) -> (c, d) slice
-        # compiles one tiny gather per distinct-count, c <= m_slots of them)
-        return new_params, updates[:c], np.asarray(losses)[:c]
+            # updates stay a device array: the gradient store scatters them
+            # back into G without a host round-trip (the (m_slots, d) -> (c, d)
+            # slice compiles one tiny gather per distinct-count, c <= m_slots)
+            updates = updates[:c]
+        with TraceAnnotation("fl.local_work.wait"):
+            losses = np.asarray(losses)[:c]
+        return new_params, updates, losses
 
 
 # --------------------------------------------------------------------------

@@ -25,8 +25,8 @@ Continuous service (the churn-tolerant path): a
 :class:`~repro.fl.population.PopulationProcess` turns the fixed-n batch
 loop into a long-running service. Each round runs as named phases —
 
-  draw ← availability mask → local work → drop resolution → aggregate
-  → observe
+  availability mask → draw → drop resolution → local work + aggregate
+  → observe → eval → record
 
 — where the sampler conditions its draw on the round's availability mask
 (re-normalized urns, unbiased over the available set), a client that
@@ -40,6 +40,19 @@ on a ``checkpoint_every`` cadence, and :meth:`FederatedServer.resume`
 reconstructs it so a killed service continues **bit-identically** to an
 uninterrupted run (pinned in ``tests/test_service_resume.py``; for
 ``planner="async"`` the checkpoint first forces the sync fixed point).
+
+Tracing: every round is a ``jax.profiler.StepTraceAnnotation`` named
+``fl.round`` (``step_num`` = the round), and each phase above a
+``TraceAnnotation`` inside it: ``fl.availability``, ``fl.draw``,
+``fl.resolve``, ``fl.local_work`` (the batched engine adds
+``fl.local_work.prep`` / ``.dispatch`` / ``.wait``), ``fl.observe``,
+``fl.eval`` (``fl.eval.h2d``, the test-set copy, and ``fl.eval.run``) and
+``fl.record``. They land in a profiler trace on the device trace's clock
+when one is active (``jax.profiler.trace``) and cost about a microsecond
+each otherwise; none blocks or moves data. Counters ride as arguments:
+``bytes`` (host-to-device bytes) on ``fl.eval.h2d`` and
+``fl.local_work.dispatch``, ``distinct`` and ``slots`` on
+``fl.local_work.prep``.
 """
 from __future__ import annotations
 
@@ -48,8 +61,10 @@ import json
 import warnings
 from typing import Callable, Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.core.samplers.base import ClientSampler
 from repro.data.federated import FederatedDataset
@@ -130,6 +145,12 @@ class FederatedServer:
         self._rng = np.random.default_rng(config.seed)
         self.history = History()
         self._x_test, self._y_test = dataset.global_test()
+        # what the per-round test-set copy moves to the device, in the dtypes
+        # jnp.asarray gives it (the ``bytes`` counter of ``fl.eval.h2d``)
+        self._test_bytes = sum(
+            a.size * jax.dtypes.canonicalize_dtype(a.dtype).itemsize
+            for a in (self._x_test, self._y_test)
+        )
         # classes each client can contribute — O(total samples) once, so the
         # per-round distinct-class count is a union of tiny class sets
         self._client_classes = [np.unique(c.y_train) for c in dataset.clients]
@@ -196,14 +217,14 @@ class FederatedServer:
 
     # -- round phases --------------------------------------------------------
     # run_round = availability → draw → drop resolution → local work +
-    # aggregate → observe. The phases are separate methods so the continuous
-    # service's failure points are named and individually testable. Drop
-    # resolution happens *before* engine dispatch because the engine fuses
-    # local work and aggregation into one jitted step: a dropped client still
-    # occupies its padded slot (stable shapes, stable rng stream) but its
-    # aggregation weight is zeroed and its mass falls back on the current
-    # global model (eq. 3's stale term) — exactly "the device computed, the
-    # result never arrived".
+    # aggregate → observe → eval → record. The phases are separate methods
+    # so the continuous service's failure points are named and individually
+    # testable. Drop resolution happens *before* engine dispatch because the
+    # engine fuses local work and aggregation into one jitted step: a
+    # dropped client still occupies its padded slot (stable shapes, stable
+    # rng stream) but its aggregation weight is zeroed and its mass falls
+    # back on the current global model (eq. 3's stale term) — exactly "the
+    # device computed, the result never arrived".
 
     def _phase_availability(self, t: int) -> tuple[Optional[np.ndarray], int]:
         """(mask, n_available); (None, -1) without a population process."""
@@ -304,121 +325,136 @@ class FederatedServer:
             )
         return self._round_compat(distinct, weights, stale_weight)
 
+    def _phase_eval(self, t: int) -> float:
+        """Test accuracy of the current model; NaN off the ``eval_every`` cadence."""
+        if t % self.cfg.eval_every:
+            return float("nan")
+        with TraceAnnotation("fl.eval"):
+            with TraceAnnotation("fl.eval.h2d", bytes=self._test_bytes):
+                x_test, y_test = jnp.asarray(self._x_test), jnp.asarray(self._y_test)
+            with TraceAnnotation("fl.eval.run"):
+                return float(self.acc_fn(self.params, x_test, y_test))
+
     def run_round(self, t: int) -> RoundRecord:
-        cfg = self.cfg
-        available, n_available = self._phase_availability(t)
-        # scheduler prologue: flush last round's harvested straggler updates
-        # into the gradient store *before* this round draws from it
-        n_harvested = (
-            int(self.scheduler.begin_round(t, self.sampler))
-            if self.scheduler is not None
-            else 0
-        )
-        result, distinct, weights, plan_version, plan_lag = self._phase_draw(
-            t, available
-        )
-        stale_weight = result.stale_weight
-        if self.scheduler is not None:
-            # round-closing rule: mark stragglers late (weight → stale term,
-            # update harvested below) before mid-round drops resolve
-            weights, stale_weight, late = self.scheduler.resolve(
-                t, distinct, weights, stale_weight
+        with TraceAnnotation("fl.availability"):
+            available, n_available = self._phase_availability(t)
+            # scheduler prologue: flush last round's harvested straggler
+            # updates into the gradient store *before* this round draws from it
+            n_harvested = (
+                int(self.scheduler.begin_round(t, self.sampler))
+                if self.scheduler is not None
+                else 0
             )
-        else:
-            late = np.zeros(distinct.shape, dtype=bool)
-        weights, stale_weight, dropped = self._phase_drop_resolution(
-            t, distinct, weights, stale_weight, late=late
-        )
-        n_dropped = int(dropped.sum())
-        # a participant that both straggled and crashed is a crash: the
-        # result never arrived, so there is nothing to harvest either
-        late = late & ~dropped
-        n_late = int(late.sum())
+        with TraceAnnotation("fl.draw"):
+            result, distinct, weights, plan_version, plan_lag = self._phase_draw(
+                t, available
+            )
+        with TraceAnnotation("fl.resolve"):
+            stale_weight = result.stale_weight
+            if self.scheduler is not None:
+                # round-closing rule: mark stragglers late (weight → stale
+                # term, update harvested below) before mid-round drops resolve
+                weights, stale_weight, late = self.scheduler.resolve(
+                    t, distinct, weights, stale_weight
+                )
+            else:
+                late = np.zeros(distinct.shape, dtype=bool)
+            weights, stale_weight, dropped = self._phase_drop_resolution(
+                t, distinct, weights, stale_weight, late=late
+            )
+            n_dropped = int(dropped.sum())
+            # a participant that both straggled and crashed is a crash: the
+            # result never arrived, so there is nothing to harvest either
+            late = late & ~dropped
+            n_late = int(late.sum())
 
-        self.params, updates_flat, losses = self._phase_local_work(
-            distinct, weights, stale_weight
-        )
-
-        if n_late and self.scheduler is not None:
-            # harvest: late updates were computed (the engine ran their
-            # padded slots) — buffer host copies for next round's store
-            self.scheduler.collect(t, distinct[late], updates_flat[np.asarray(late)])
-
-        # observe: feed representative gradients back (Algorithm 2's input) —
-        # on-time survivors only; a dropped client's update never reached the
-        # server and a straggler's arrives next round via the harvest path,
-        # so neither refreshes the similarity state here
-        keep = ~(dropped | late)
-        contributing = distinct[keep]
-        if contributing.size:
-            self.sampler.observe_updates(
-                contributing, updates_flat[np.asarray(keep)]
+        with TraceAnnotation("fl.local_work"):
+            self.params, updates_flat, losses = self._phase_local_work(
+                distinct, weights, stale_weight
             )
 
-        # rebuild-cost telemetry is read *after* observe_updates: the drift
-        # statistic (and any sync rebuild) for this round happens there
-        plan_build_ms, plan_drift = self.sampler.plan_cost_telemetry()
+        with TraceAnnotation("fl.observe"):
+            if n_late and self.scheduler is not None:
+                # harvest: late updates were computed (the engine ran their
+                # padded slots) — buffer host copies for next round's store
+                self.scheduler.collect(
+                    t, distinct[late], updates_flat[np.asarray(late)]
+                )
 
-        # availability fold: the mask plus this round's graded outcomes —
-        # on-time 1.0, late late_credit, crashed 0.0 (see fl.availability)
-        if self.availability is not None:
-            self.availability.update(
-                available,
-                on_time=contributing,
-                late=distinct[late],
-                crashed=distinct[dropped],
-            )
-            avail_score_min = self.availability.min_score()
-        else:
-            avail_score_min = -1.0
+            # observe: feed representative gradients back (Algorithm 2's
+            # input) — on-time survivors only; a dropped client's update never
+            # reached the server and a straggler's arrives next round via the
+            # harvest path, so neither refreshes the similarity state here
+            keep = ~(dropped | late)
+            contributing = distinct[keep]
+            if contributing.size:
+                self.sampler.observe_updates(
+                    contributing, updates_flat[np.asarray(keep)]
+                )
 
-        classes = (
-            np.unique(
-                np.concatenate([self._client_classes[int(c)] for c in contributing])
+            # rebuild-cost telemetry is read *after* observe_updates: the
+            # drift statistic (and any sync rebuild) happens there
+            plan_build_ms, plan_drift = self.sampler.plan_cost_telemetry()
+
+        # the record is built on both sides of eval, in the round's order
+        with TraceAnnotation("fl.record"):
+            # availability fold: the mask plus this round's graded outcomes —
+            # on-time 1.0, late late_credit, crashed 0.0 (see fl.availability)
+            if self.availability is not None:
+                self.availability.update(
+                    available,
+                    on_time=contributing,
+                    late=distinct[late],
+                    crashed=distinct[dropped],
+                )
+                avail_score_min = self.availability.min_score()
+            else:
+                avail_score_min = -1.0
+
+            classes = (
+                np.unique(
+                    np.concatenate([self._client_classes[int(c)] for c in contributing])
+                )
+                if contributing.size
+                else np.empty(0, np.int64)
             )
-            if contributing.size
-            else np.empty(0, np.int64)
-        )
-        test_acc = (
-            float(self.acc_fn(self.params, jnp.asarray(self._x_test), jnp.asarray(self._y_test)))
-            if (t % cfg.eval_every == 0)
-            else float("nan")
-        )
-        agg_weights = result.agg_weights
-        if n_dropped or n_late:
-            agg_weights = np.array(agg_weights, dtype=np.float64, copy=True)
-            agg_weights[distinct[dropped | late]] = 0.0
-        live_mass = float(weights.sum())
-        rec = RoundRecord(
-            round=t,
-            # dropped/late participants carry zero weight, so the round loss
-            # averages over on-time survivors only; a round that lost every
-            # participant to lateness aggregated stale-only mass — no loss
-            train_loss=(
-                float(np.average(losses, weights=weights))
-                if live_mass > 0
-                else float("nan")
-            ),
-            test_acc=test_acc,
-            n_distinct_clients=len(distinct),
-            n_distinct_classes=len(classes),
-            agg_weights=agg_weights,
-            plan_version=plan_version,
-            plan_lag_rounds=plan_lag,
-            plan_build_ms=plan_build_ms,
-            plan_drift=plan_drift,
-            n_available=n_available,
-            n_dropped=n_dropped,
-            # n_late also counts draws the scheduler discarded at draw time
-            # (overselection surplus); round_status tracks actual stragglers
-            # and crashes only — planned surplus is not degradation
-            n_late=n_late
-            + (self.scheduler.n_late_extra() if self.scheduler is not None else 0),
-            n_harvested=n_harvested,
-            avail_score_min=avail_score_min,
-            round_status="degraded" if (n_dropped or n_late) else "ok",
-        )
-        self.history.append(rec)
+        test_acc = self._phase_eval(t)
+        with TraceAnnotation("fl.record"):
+            agg_weights = result.agg_weights
+            if n_dropped or n_late:
+                agg_weights = np.array(agg_weights, dtype=np.float64, copy=True)
+                agg_weights[distinct[dropped | late]] = 0.0
+            live_mass = float(weights.sum())
+            rec = RoundRecord(
+                round=t,
+                # dropped/late participants carry zero weight, so the round loss
+                # averages over on-time survivors only; a round that lost every
+                # participant to lateness aggregated stale-only mass — no loss
+                train_loss=(
+                    float(np.average(losses, weights=weights))
+                    if live_mass > 0
+                    else float("nan")
+                ),
+                test_acc=test_acc,
+                n_distinct_clients=len(distinct),
+                n_distinct_classes=len(classes),
+                agg_weights=agg_weights,
+                plan_version=plan_version,
+                plan_lag_rounds=plan_lag,
+                plan_build_ms=plan_build_ms,
+                plan_drift=plan_drift,
+                n_available=n_available,
+                n_dropped=n_dropped,
+                # n_late also counts draws the scheduler discarded at draw time
+                # (overselection surplus); round_status tracks actual stragglers
+                # and crashes only — planned surplus is not degradation
+                n_late=n_late
+                + (self.scheduler.n_late_extra() if self.scheduler is not None else 0),
+                n_harvested=n_harvested,
+                avail_score_min=avail_score_min,
+                round_status="degraded" if (n_dropped or n_late) else "ok",
+            )
+            self.history.append(rec)
         self._round_cursor = t + 1
         return rec
 
@@ -450,27 +486,28 @@ class FederatedServer:
         cfg = self.cfg
         every = int(cfg.checkpoint_every or 0)
         for t in range(self._start_round, cfg.n_rounds):
-            try:
-                rec = self.run_round(t)
-            except EmptyRoundError:
-                if not skip_empty:
-                    raise
-                n_avail = (
-                    int(self.population.available_mask(t).sum())
-                    if self.population is not None
-                    else -1
-                )
-                rec = RoundRecord(
-                    round=t,
-                    train_loss=float("nan"),
-                    test_acc=float("nan"),
-                    n_distinct_clients=0,
-                    n_distinct_classes=0,
-                    n_available=n_avail,
-                    round_status="empty",
-                )
-                self.history.append(rec)
-                self._round_cursor = t + 1
+            with StepTraceAnnotation("fl.round", step_num=t):
+                try:
+                    rec = self.run_round(t)
+                except EmptyRoundError:
+                    if not skip_empty:
+                        raise
+                    n_avail = (
+                        int(self.population.available_mask(t).sum())
+                        if self.population is not None
+                        else -1
+                    )
+                    rec = RoundRecord(
+                        round=t,
+                        train_loss=float("nan"),
+                        test_acc=float("nan"),
+                        n_distinct_clients=0,
+                        n_distinct_classes=0,
+                        n_available=n_avail,
+                        round_status="empty",
+                    )
+                    self.history.append(rec)
+                    self._round_cursor = t + 1
             if on_round is not None:
                 on_round(rec)
             if every and cfg.checkpoint_path and (t + 1) % every == 0:

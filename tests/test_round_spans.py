@@ -1,0 +1,184 @@
+"""The round's profiler spans: names, nesting, step numbers and counters, and
+a traced run that computes bit for bit what an untraced one computes."""
+import glob
+import json
+import os
+
+import jax
+import numpy as np
+import pytest
+
+import repro.fl.engine as engine_mod
+from repro.core import MDSampler
+from repro.core.samplers.base import ClientSampler
+from repro.core.types import SampleResult
+from repro.fl import EmptyRoundError, FederatedServer, FLConfig, by_class_shards
+from repro.models.simple import init_mlp
+from repro.optim import sgd
+
+ROUNDS, M = 3, 6
+#: Each span of a batched-engine round and the span it nests in.
+PARENT = {
+    "fl.availability": "fl.round",
+    "fl.draw": "fl.round",
+    "fl.resolve": "fl.round",
+    "fl.local_work": "fl.round",
+    "fl.local_work.prep": "fl.local_work",
+    "fl.local_work.dispatch": "fl.local_work",
+    "fl.local_work.wait": "fl.local_work",
+    "fl.observe": "fl.round",
+    "fl.eval": "fl.round",
+    "fl.eval.h2d": "fl.eval",
+    "fl.eval.run": "fl.eval",
+    "fl.record": "fl.round",
+}
+
+
+@pytest.fixture(scope="module")
+def dataset():
+    return by_class_shards(dim=16, noise=0.8, train_per_client=40, test_per_client=10, seed=0)
+
+
+def _server(dataset, engine="batched", sampler=None):
+    cfg = FLConfig(n_rounds=ROUNDS, n_local_steps=3, batch_size=8, seed=5, engine=engine)
+    sampler = sampler or MDSampler(dataset.population, M, seed=11)
+    return FederatedServer(dataset, sampler, init_mlp((16, 16, 10), seed=1), sgd(0.05), cfg)
+
+
+def _fl_events(log_dir):
+    """``(name, start, end, arguments)`` of every ``fl.*`` host event, by start."""
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(os.path.join(log_dir, "plugins", "profile", "*", "*.xplane.pb"))
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host"):
+            for line in plane.lines:
+                out.extend(
+                    (e.name, e.start_ns, e.start_ns + e.duration_ns, {k: v for k, v in e.stats})
+                    for e in line.events
+                    if e.name.startswith("fl.")
+                )
+    return sorted(out, key=lambda e: (e[1], -e[2]))
+
+
+def _traced_run(srv, log_dir):
+    with jax.profiler.trace(str(log_dir)):
+        history = srv.run()
+        jax.block_until_ready(srv.params)
+    return history, _fl_events(str(log_dir))
+
+
+def _canon_json(history) -> str:
+    """History JSON with wall-clock telemetry (plan_build_ms) normalized."""
+    recs = json.loads(history.to_json())
+    for r in recs:
+        r["plan_build_ms"] = -1.0
+    return json.dumps(recs)
+
+
+def _enclosing(events, child, name):
+    """The ``name`` events that enclose ``child``."""
+    _, start, end, _ = child
+    return [e for e in events if e[0] == name and e[1] <= start and end <= e[2]]
+
+
+@pytest.fixture(scope="module")
+def traced(dataset, tmp_path_factory):
+    """A batched-engine run under a trace, with the device bytes each round
+    actually moved: the step's four per-round inputs and the test set."""
+    dispatched, evaluated = [], []
+    step = engine_mod.batched_round_step
+
+    def recording_step(*args, **kwargs):
+        dispatched.append(sum(a.nbytes for a in args[3:7]))
+        return step(*args, **kwargs)
+
+    srv = _server(dataset)
+    acc_fn = srv.acc_fn
+
+    def recording_acc(params, x, y):
+        evaluated.append(x.nbytes + y.nbytes)
+        return acc_fn(params, x, y)
+
+    srv.acc_fn = recording_acc
+    engine_mod.batched_round_step = recording_step
+    try:
+        history, events = _traced_run(srv, tmp_path_factory.mktemp("trace"))
+    finally:
+        engine_mod.batched_round_step = step
+    return {"srv": srv, "history": history, "events": events,
+            "dispatched": dispatched, "evaluated": evaluated}
+
+
+def test_one_round_span_per_round_with_its_step_number(traced):
+    rounds = [e for e in traced["events"] if e[0] == "fl.round"]
+    assert [e[3]["step_num"] for e in rounds] == list(range(ROUNDS))
+    assert [r.round for r in traced["history"].records] == list(range(ROUNDS))
+
+
+@pytest.mark.parametrize("child", sorted(PARENT))
+def test_each_span_nests_in_its_parent_once_per_round(traced, child):
+    events = traced["events"]
+    mine = [e for e in events if e[0] == child]
+    per_round = 2 if child == "fl.record" else 1  # opened on both sides of eval
+    assert len(mine) == per_round * ROUNDS
+    for e in mine:
+        assert len(_enclosing(events, e, PARENT[child])) == 1
+        assert len(_enclosing(events, e, "fl.round")) == 1
+
+
+def test_byte_counters_equal_the_device_arrays_built(traced):
+    events = traced["events"]
+    dispatch = [e[3]["bytes"] for e in events if e[0] == "fl.local_work.dispatch"]
+    h2d = [e[3]["bytes"] for e in events if e[0] == "fl.eval.h2d"]
+    assert dispatch == traced["dispatched"] and len(dispatch) == ROUNDS
+    assert h2d == traced["evaluated"] and len(h2d) == ROUNDS
+    assert dispatch[0] == 4 * (M + M * 3 * 8 + M + 1)  # slots, indices, weights, stale
+
+
+def test_slot_counters_match_the_round_records(traced):
+    prep = [e[3] for e in traced["events"] if e[0] == "fl.local_work.prep"]
+    records = traced["history"].records
+    assert [p["distinct"] for p in prep] == [r.n_distinct_clients for r in records]
+    assert all(p["slots"] == M for p in prep)
+
+
+def test_a_traced_run_computes_what_an_untraced_run_computes(dataset, traced):
+    plain = _server(dataset)
+    history = plain.run()
+    assert _canon_json(history) == _canon_json(traced["history"])
+    for k, v in plain.params.items():
+        np.testing.assert_array_equal(np.asarray(v), np.asarray(traced["srv"].params[k]))
+
+
+def test_the_compat_loop_has_the_server_spans_only(dataset, tmp_path):
+    _, events = _traced_run(_server(dataset, engine="compat"), tmp_path)
+    names = {e[0] for e in events}
+    assert names == {"fl.round"} | set(PARENT) - {
+        "fl.local_work.prep", "fl.local_work.dispatch", "fl.local_work.wait"}
+
+
+class _OddRoundsEmptySampler(ClientSampler):
+    """Gives its draw zero weight in odd rounds, which makes them empty."""
+
+    def sample(self, round_idx):
+        weights = np.zeros(self.population.n_clients)
+        if round_idx % 2 == 0:
+            weights[:M] = 1.0 / M
+        return SampleResult(clients=np.arange(M, dtype=np.int64), agg_weights=weights)
+
+
+def test_an_empty_round_is_a_round_span_too(dataset, tmp_path):
+    srv = _server(dataset, sampler=_OddRoundsEmptySampler(dataset.population, M))
+    with jax.profiler.trace(str(tmp_path)):
+        history = srv.run(skip_empty=True)
+    events = _fl_events(str(tmp_path))
+    assert [r.round_status for r in history.records] == ["ok", "empty", "ok"]
+    rounds = [e for e in events if e[0] == "fl.round"]
+    assert [e[3]["step_num"] for e in rounds] == [0, 1, 2]
+    # the empty round ends at the draw: nothing of local work or eval in it
+    inside = {e[0] for e in events if _enclosing(events, e, "fl.round") == [rounds[1]]}
+    assert inside == {"fl.round", "fl.availability", "fl.draw"}
+    with pytest.raises(EmptyRoundError):
+        _server(dataset, sampler=_OddRoundsEmptySampler(dataset.population, M)).run()
