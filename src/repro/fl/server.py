@@ -46,11 +46,13 @@ Tracing: every round is a ``jax.profiler.StepTraceAnnotation`` named
 ``TraceAnnotation`` inside it: ``fl.availability``, ``fl.draw``,
 ``fl.resolve``, ``fl.local_work`` (the batched engine adds
 ``fl.local_work.prep`` / ``.dispatch`` / ``.wait``), ``fl.observe``,
-``fl.eval`` (``fl.eval.h2d``, the test-set copy, and ``fl.eval.run``) and
-``fl.record``. They land in a profiler trace on the device trace's clock
-when one is active (``jax.profiler.trace``) and cost about a microsecond
-each otherwise; none blocks or moves data. Counters ride as arguments:
-``bytes`` (host-to-device bytes) on ``fl.eval.h2d`` and
+``fl.eval`` (with ``fl.eval.run``, the compiled accuracy call) and
+``fl.record``. The test set is copied to the device once, when the server
+is built, under ``fl.eval.stage`` outside any round; no round copies it.
+The spans land in a profiler trace on the device trace's clock when one is
+active (``jax.profiler.trace``) and cost about a microsecond each
+otherwise; none blocks or moves data. Counters ride as arguments:
+``bytes`` (host-to-device bytes) on ``fl.eval.stage`` and
 ``fl.local_work.dispatch``, ``distinct`` and ``slots`` on
 ``fl.local_work.prep``.
 """
@@ -65,6 +67,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.profiler import StepTraceAnnotation, TraceAnnotation
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.samplers.base import ClientSampler
 from repro.data.federated import FederatedDataset
@@ -130,7 +133,8 @@ class FederatedServer:
         scores; attach it to the sampler too
         (``StoreBackedSampler.attach_availability``) to restrict plan
         rebuilds to the recently-seen fleet. Both checkpoint inside
-        ``ServerState`` when present."""
+        ``ServerState`` when present. The global test set is staged on the
+        device here and stays there; ``acc_fn`` is jitted over it."""
         engine_factory = ENGINES.get(config.engine)  # precise unknown-name error
         self.dataset = dataset
         self.sampler = sampler
@@ -138,19 +142,14 @@ class FederatedServer:
         self.opt = optimizer
         self.cfg = config
         self.loss_fn = loss_fn
-        self.acc_fn = acc_fn
+        # one compiled program over the staged test set; an attribute, so a
+        # caller can still swap the function (or wrap it) after construction
+        self.acc_fn = jax.jit(acc_fn)
         self.population = population
         self.scheduler = scheduler
         self.availability = availability
         self._rng = np.random.default_rng(config.seed)
         self.history = History()
-        self._x_test, self._y_test = dataset.global_test()
-        # what the per-round test-set copy moves to the device, in the dtypes
-        # jnp.asarray gives it (the ``bytes`` counter of ``fl.eval.h2d``)
-        self._test_bytes = sum(
-            a.size * jax.dtypes.canonicalize_dtype(a.dtype).itemsize
-            for a in (self._x_test, self._y_test)
-        )
         # classes each client can contribute — O(total samples) once, so the
         # per-round distinct-class count is a union of tiny class sets
         self._client_classes = [np.unique(c.y_train) for c in dataset.clients]
@@ -182,11 +181,32 @@ class FederatedServer:
                 mesh = None  # the compat loop never shards; a stale mesh here
                 # would be handed to the factory and pin devices for nothing
         self._engine = engine_factory(dataset, slots, config, mesh)
+        self._stage_test_set(mesh)
         # service cursor: the next round to run. run()/resume() maintain it so
         # a restored server continues exactly where the checkpoint left off.
         self._start_round = 0
         self._round_cursor = 0
         self._closed = False
+
+    def _stage_test_set(self, mesh) -> None:
+        """Copy the global test set to the device once, for the server's life.
+
+        With no mesh it lands on the default device; on a mesh it is
+        replicated, as the round step's global params are, so eval moves no
+        data in any round. It stays resident beside the engine's staged
+        client data but is not counted against ``max_staged_bytes``.
+        """
+        x, y = self.dataset.global_test()
+        if mesh is None:
+            place = jnp.asarray
+        else:
+            place = lambda a: jax.device_put(a, NamedSharding(mesh, P()))
+        # the ``bytes`` counter is what the copy moves, in the dtypes it gives
+        self._test_bytes = sum(
+            a.size * jax.dtypes.canonicalize_dtype(a.dtype).itemsize for a in (x, y)
+        )
+        with TraceAnnotation("fl.eval.stage", bytes=self._test_bytes):
+            self._x_test, self._y_test = place(x), place(y)
 
     # ------------------------------------------------------------------
     def _round_compat(self, distinct: np.ndarray, weights: np.ndarray, stale_weight: float):
@@ -329,11 +349,8 @@ class FederatedServer:
         """Test accuracy of the current model; NaN off the ``eval_every`` cadence."""
         if t % self.cfg.eval_every:
             return float("nan")
-        with TraceAnnotation("fl.eval"):
-            with TraceAnnotation("fl.eval.h2d", bytes=self._test_bytes):
-                x_test, y_test = jnp.asarray(self._x_test), jnp.asarray(self._y_test)
-            with TraceAnnotation("fl.eval.run"):
-                return float(self.acc_fn(self.params, x_test, y_test))
+        with TraceAnnotation("fl.eval"), TraceAnnotation("fl.eval.run"):
+            return float(self.acc_fn(self.params, self._x_test, self._y_test))
 
     def run_round(self, t: int) -> RoundRecord:
         with TraceAnnotation("fl.availability"):

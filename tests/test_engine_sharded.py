@@ -9,6 +9,7 @@ import os
 import subprocess
 import sys
 
+import numpy as np
 import pytest
 
 ROOT = os.path.join(os.path.dirname(__file__), "..")
@@ -38,16 +39,20 @@ def run(mesh_spec, seed=7):
         ds, MDSampler(ds.population, 8, seed=seed), params, sgd(0.08), cfg
     )
     srv.run()
+    staged = [a.sharding for a in (srv._x_test, srv._y_test)]
     return (
         np.asarray(flatten_params(srv.params)),
         srv.history.series("train_loss"),
         srv._engine.per_device_staged_bytes(),
+        {"acc": srv.history.series("test_acc").tolist(),
+         "replicated": all(s.is_fully_replicated for s in staged),
+         "devices": [len(s.device_set) for s in staged]},
     )
 
 
-p1, l1, b1 = run(None)
-p4, l4, b4 = run("4x1")
-pa, la, ba = run("auto")
+p1, l1, b1, e1 = run(None)
+p4, l4, b4, e4 = run("4x1")
+pa, la, ba, ea = run("auto")
 
 # the pod-scale LM round driver on the same host mesh: client axis sharded,
 # params replicated over "data" (launch.fl_train's cross-silo layout)
@@ -95,6 +100,8 @@ print(json.dumps({
     "est_4x1": int(est4),
     "lm_losses_finite": bool(np.isfinite(np.asarray(lm_losses)).all()),
     "lm_m_guard": m_guard,
+    "eval_1": e1,
+    "eval_4x1": e4,
 }))
 """
 
@@ -136,3 +143,13 @@ def test_federated_lm_driver_runs_on_host_mesh(sharded_results):
     rejects an m the data-parallel degree does not divide."""
     assert sharded_results["lm_losses_finite"]
     assert sharded_results["lm_m_guard"]
+
+
+def test_mesh_keeps_a_replicated_test_set_with_one_device_accuracy(sharded_results):
+    """On a 4x1 mesh the test set is staged once, whole on every device, and
+    each round's accuracy is the one-device run's."""
+    one, mesh = sharded_results["eval_1"], sharded_results["eval_4x1"]
+    assert one["devices"] == [1, 1]
+    assert mesh["replicated"] and mesh["devices"] == [4, 4]
+    assert len(mesh["acc"]) == 3
+    np.testing.assert_allclose(mesh["acc"], one["acc"], rtol=0, atol=1e-6)

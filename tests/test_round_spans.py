@@ -13,7 +13,7 @@ from repro.core import MDSampler
 from repro.core.samplers.base import ClientSampler
 from repro.core.types import SampleResult
 from repro.fl import EmptyRoundError, FederatedServer, FLConfig, by_class_shards
-from repro.models.simple import init_mlp
+from repro.models.simple import accuracy, init_mlp
 from repro.optim import sgd
 
 ROUNDS, M = 3, 6
@@ -28,7 +28,6 @@ PARENT = {
     "fl.local_work.wait": "fl.local_work",
     "fl.observe": "fl.round",
     "fl.eval": "fl.round",
-    "fl.eval.h2d": "fl.eval",
     "fl.eval.run": "fl.eval",
     "fl.record": "fl.round",
 }
@@ -86,8 +85,8 @@ def _enclosing(events, child, name):
 @pytest.fixture(scope="module")
 def traced(dataset, tmp_path_factory):
     """A batched-engine run under a trace, with the device bytes each round
-    actually moved: the step's four per-round inputs and the test set."""
-    dispatched, evaluated = [], []
+    actually moved: the step's four per-round inputs."""
+    dispatched = []
     step = engine_mod.batched_round_step
 
     def recording_step(*args, **kwargs):
@@ -95,20 +94,12 @@ def traced(dataset, tmp_path_factory):
         return step(*args, **kwargs)
 
     srv = _server(dataset)
-    acc_fn = srv.acc_fn
-
-    def recording_acc(params, x, y):
-        evaluated.append(x.nbytes + y.nbytes)
-        return acc_fn(params, x, y)
-
-    srv.acc_fn = recording_acc
     engine_mod.batched_round_step = recording_step
     try:
         history, events = _traced_run(srv, tmp_path_factory.mktemp("trace"))
     finally:
         engine_mod.batched_round_step = step
-    return {"srv": srv, "history": history, "events": events,
-            "dispatched": dispatched, "evaluated": evaluated}
+    return {"srv": srv, "history": history, "events": events, "dispatched": dispatched}
 
 
 def test_one_round_span_per_round_with_its_step_number(traced):
@@ -131,9 +122,7 @@ def test_each_span_nests_in_its_parent_once_per_round(traced, child):
 def test_byte_counters_equal_the_device_arrays_built(traced):
     events = traced["events"]
     dispatch = [e[3]["bytes"] for e in events if e[0] == "fl.local_work.dispatch"]
-    h2d = [e[3]["bytes"] for e in events if e[0] == "fl.eval.h2d"]
     assert dispatch == traced["dispatched"] and len(dispatch) == ROUNDS
-    assert h2d == traced["evaluated"] and len(h2d) == ROUNDS
     assert dispatch[0] == 4 * (M + M * 3 * 8 + M + 1)  # slots, indices, weights, stale
 
 
@@ -157,6 +146,47 @@ def test_the_compat_loop_has_the_server_spans_only(dataset, tmp_path):
     names = {e[0] for e in events}
     assert names == {"fl.round"} | set(PARENT) - {
         "fl.local_work.prep", "fl.local_work.dispatch", "fl.local_work.wait"}
+
+
+def test_the_test_set_is_staged_once_at_construction(dataset, tmp_path):
+    with jax.profiler.trace(str(tmp_path)):
+        srv = _server(dataset)
+        srv.run()
+        jax.block_until_ready(srv.params)
+    events = _fl_events(str(tmp_path))
+    (stage,) = [e for e in events if e[0] == "fl.eval.stage"]
+    assert not _enclosing(events, stage, "fl.round")
+    assert stage[2] <= min(e[1] for e in events if e[0] == "fl.round")
+    assert stage[3]["bytes"] == srv._x_test.nbytes + srv._y_test.nbytes == srv._test_bytes
+
+
+@pytest.mark.parametrize("engine", ["batched", "compat"])
+def test_every_round_evaluates_on_the_same_device_arrays(dataset, tmp_path, engine):
+    srv = _server(dataset, engine=engine)
+    seen, acc_fn = [], srv.acc_fn
+
+    def recording_acc(params, x, y):
+        seen.append((x, y))
+        return acc_fn(params, x, y)
+
+    srv.acc_fn = recording_acc
+    _, events = _traced_run(srv, tmp_path)
+    assert len(seen) == ROUNDS
+    x0, y0 = seen[0]
+    assert isinstance(x0, jax.Array) and isinstance(y0, jax.Array)
+    assert all(x is x0 and y is y0 for x, y in seen)
+    assert not [e for e in events if e[0] == "fl.eval.h2d"]
+    assert len([e for e in events if e[0] == "fl.eval.run"]) == ROUNDS
+
+
+def test_staged_accuracy_equals_plain_accuracy_on_host_copies(dataset):
+    x_host, y_host = dataset.global_test()
+    srv = _server(dataset)
+    plain = []
+    srv.run(on_round=lambda _: plain.append(float(accuracy(srv.params, x_host, y_host))))
+    staged = srv.history.series("test_acc")
+    assert len(plain) == ROUNDS
+    np.testing.assert_allclose(staged, plain, rtol=0, atol=1.0 / len(y_host))
 
 
 class _OddRoundsEmptySampler(ClientSampler):
