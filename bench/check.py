@@ -9,7 +9,8 @@ Each number is a reading of one guarantee over the checked rounds:
   the reference's (exact; limit 0);
 * ``loss_rel``: the largest relative gap of a round's train loss;
 * ``update_gap``: the first round's change of the global model, by the worst
-  leaf: ``| |d_prog| - |d_ref| |`` over ``max(|d_ref|, median leaf |d_ref|)``;
+  leaf: ``| |d_prog| - |d_ref| |`` over ``max(|d_ref|, median leaf |d_ref|)``,
+  leaves named by their pytree path (:func:`reference.named_leaves`);
 * ``change_gap``: the same for the change over all checked rounds;
 * ``acc_gap``: the largest gap of a round's test accuracy;
 * ``rows_rel``: the largest relative distance of a client's representative
@@ -34,9 +35,11 @@ import math
 
 import numpy as np
 
+from reference import named_leaves
+
 
 def _leaf_gap(prog_delta: dict, ref_delta: dict) -> float:
-    names = sorted(ref_delta)
+    names = list(ref_delta)
     ref = np.array([np.linalg.norm(ref_delta[k]) for k in names])
     got = np.array([np.linalg.norm(np.asarray(prog_delta[k], np.float64)) for k in names])
     median = float(np.median(ref))
@@ -67,9 +70,12 @@ def numbers(prog: dict, ref: dict) -> dict:
     k = len(ref["loss"])
     if len(prog["loss"]) != k:
         return {"rounds_missing": float(k - len(prog["loss"]))}
-    delta = lambda side, a, b: {n: np.asarray(side["params"][b][n], np.float64)
-                                - np.asarray(side["params"][a][n], np.float64)
-                                for n in side["params"][0]}
+
+    def delta(side, a, b):
+        start, end = named_leaves(side["params"][a]), named_leaves(side["params"][b])
+        return {n: np.asarray(end[n], np.float64) - np.asarray(v, np.float64)
+                for n, v in start.items()}
+
     out = {
         "draw_mismatch": float(sum(int((np.asarray(a) != b).sum())
                                    for a, b in zip(prog["clients"], ref["clients"]))),

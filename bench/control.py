@@ -8,8 +8,9 @@ reference: the lower readings. For each control seed, the same numbers of
 what stands in the program's place:
 
 * ``control``: the reference with parameters and activations in bfloat16,
-  the precision below the configuration's (float32 at the default matmul
-  precision, whose one bfloat16 pass is already the program's);
+  the precision below the configuration's (for the MLP, float32 at the
+  default matmul precision, whose one bfloat16 pass is already the
+  program's), which the model kind's reference half is handed as its dtype;
 * ``half_batch``: the reference trained on half of every batch, the mean
   taken over the rest;
 * ``state_unchanged``: the reference with the global model never moving;
@@ -65,8 +66,8 @@ def readings(bench, cell_name: str, seeds, control_seeds) -> dict:
     per_seed = {}
     for seed in seeds:
         t0 = time.perf_counter()
-        srv, ref_inputs = harness.build_server(cfg, mix, seed)
-        rounds = harness.check_round_count(len(srv.dataset.clients), cfg["train"]["m"])
+        srv, ref_inputs = harness.build_server(cfg, mix, seed, bench_dir=bench.dir)
+        rounds = harness.rounds_checked(ref_inputs["kind"], cfg, len(srv.dataset.clients))
         cap = harness.check_rounds(srv, rounds)
         srv.close()
         del srv

@@ -2,16 +2,19 @@
 
 Everything that belongs to a cell is found by name under the benchmark's
 root: ``BENCHMARK.json`` names the cell's configuration (its ``file``) and
-traffic mix (``mixes/<traffic>.json``); the limits of its check live in
-``limits/<cell>.json`` and each per-layer metric is read by
+traffic mix (``mixes/<traffic>.json``); the configuration names its model
+kind (``models/<kind>.py``, see :func:`model_kind`); the limits of its check
+live in ``limits/<cell>.json`` and each per-layer metric is read by
 ``layer_metrics/<metric>.py`` (a ``read(ctx)`` that returns a number or
-``None``). Adding a cell adds files and entries; no code changes.
+``None``). Adding a cell, or a configuration of another model, adds files
+and entries; no code changes.
 
 A run:
 
-1. set-up (``setup_s``, from process start): the clients' data and the
-   initial weights from the seed (``inputs.py``); the server, built by the
-   program's ``build_experiment``; its first :func:`check_round_count` rounds
+1. set-up (``setup_s``, from process start): the model kind's ``build``
+   makes the clients' data and the initial weights from the seed and the
+   server, by the program's ``build_experiment``; its first
+   :func:`rounds_checked` rounds
    through ``FederatedServer.run``, recorded for the check; a warm-up of every shape
    the window can meet (one local-work dispatch, update slice and store
    scatter per distinct-client count ``1..m``);
@@ -21,11 +24,13 @@ A run:
    under the profiler with the server's phases wrapped in host spans;
 3. the check, after ``memory_peak_bytes`` is read and the server is freed: the
    reference runs as many rounds from the same inputs, building its own
-   plans (``reference.py``), and ``check.py`` compares them.
+   plans (``reference.py``, with the kind's model half), and ``check.py``
+   compares them.
 """
 from __future__ import annotations
 
 import contextlib
+import functools
 import gc
 import importlib.util
 import json
@@ -80,19 +85,53 @@ class Bench:
         return [m for m in self.spec[kind] if cell in m.get("workloads", [cell])]
 
     def reader(self, metric: str):
-        path = self.dir / "layer_metrics" / f"{metric}.py"
-        name = "layer_metric_" + metric.replace(".", "_")
-        spec = importlib.util.spec_from_file_location(name, path)
-        module = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(module)
-        return module.read
+        return _load(self.dir / "layer_metrics" / f"{metric}.py", "layer_metric_").read
+
+
+def _load(path: Path, prefix: str):
+    """The module in the file at ``path``, named ``prefix`` + its stem."""
+    name = prefix + path.stem.replace(".", "_").replace("-", "_")
+    spec = importlib.util.spec_from_file_location(name, path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+@functools.lru_cache(maxsize=None)
+def _kind_module(path: Path):
+    return _load(path, "bench_model_")
+
+
+def model_kind(cfg: dict, bench_dir: Path = BENCH_DIR):
+    """The configuration's model kind: the module ``models/<kind>.py`` named by
+    its ``"model": {"kind": ...}``; without a ``model`` section, the MLP of
+    ``train.hidden``. A kind defines
+
+    * ``build(cfg, mix, seeds) -> (server, reference inputs)``: the clients'
+      data, the initial weights, the server (:func:`experiment_dict` and the
+      kind's own model) and the reference's inputs
+      (:func:`reference_inputs`);
+    * ``local_train(dtype)`` and ``evaluate(dtype)``: the reference's model
+      half, which :func:`reference.replay` calls;
+    * ``shapes(cfg, srv) -> dict``: sizes the readers read, among them
+      ``train_flops_per_client``, the model FLOPs of one distinct client's
+      local work in a round;
+    * optionally ``check_round_count(cfg, n_clients, m)``, the rounds the
+      check covers (default :func:`check_round_count`).
+
+    One module per file, loaded once per process.
+    """
+    kind = cfg.get("model", {}).get("kind", "mlp")
+    return _kind_module(Path(bench_dir) / "models" / f"{kind}.py")
 
 
 # --------------------------------------------------------------------------
 # set-up
 # --------------------------------------------------------------------------
 def experiment_dict(cfg: dict, mix: dict, seeds: dict) -> dict:
-    """The program's ``ExperimentSpec`` of a cell."""
+    """The program's ``ExperimentSpec`` of a cell, but for the model: the
+    sampler, scheduler and population of the mix, the protocol's ``train``
+    sizes of the configuration. A model kind adds its own model."""
     tr = cfg["train"]
     spec = {
         "data": {"name": cfg["data"]["partition"]},
@@ -101,8 +140,7 @@ def experiment_dict(cfg: dict, mix: dict, seeds: dict) -> dict:
         "engine": {"name": "batched"},
         "train": {"n_rounds": 10**9, "n_local_steps": tr["n_local_steps"],
                   "batch_size": tr["batch_size"], "lr": tr["lr"],
-                  "eval_every": mix["eval_every"], "seed": seeds["train"],
-                  "hidden": tr["hidden"], "n_classes": cfg["data"]["n_classes"]},
+                  "eval_every": mix["eval_every"], "seed": seeds["train"]},
         "population": mix["population"],
         "scheduler": mix["scheduler"],
     }
@@ -111,25 +149,27 @@ def experiment_dict(cfg: dict, mix: dict, seeds: dict) -> dict:
     return spec
 
 
-def build_server(cfg: dict, mix: dict, seed: int):
-    """(server, the reference's inputs), all made from ``seed``."""
-    from repro.data.federated import ClientData, FederatedDataset
-    from repro.fl.experiment import build_experiment
+def reference_inputs(cfg: dict, mix: dict, seeds: dict, clients: list, params0) -> dict:
+    """What :func:`reference.replay` runs from, but for the model kind: the
+    clients' data ``(x_train, y_train, x_test, y_test)``, the initial weights
+    (a pytree) on the host, the seeds, the plan rule and the protocol's sizes."""
+    import jax
 
-    seeds = inputs.derive_seeds(seed)
-    clients = inputs.make_clients(cfg["data"], seeds["data"])
-    dataset = FederatedDataset([ClientData(*c) for c in clients])
-    params0 = inputs.init_params(inputs.mlp_dims(cfg), seeds["model"])
-    srv = build_experiment(experiment_dict(cfg, mix, seeds), dataset=dataset)
-    srv.params = params0
     tr = cfg["train"]
-    ref_inputs = {
+    return {
         "clients": clients, "seeds": seeds, "rule": reference.plan_rule(mix),
         "m": tr["m"], "lr": tr["lr"],
         "n_local_steps": tr["n_local_steps"], "batch_size": tr["batch_size"],
-        "params0": {k: np.asarray(v) for k, v in params0.items()},
+        "params0": jax.tree_util.tree_map(np.asarray, params0),
     }
-    return srv, ref_inputs
+
+
+def build_server(cfg: dict, mix: dict, seed: int, *, bench_dir: Path = BENCH_DIR):
+    """(server, the reference's inputs), all made from ``seed`` by the
+    configuration's model kind; the inputs hold the kind under ``"kind"``."""
+    kind = model_kind(cfg, bench_dir)
+    srv, ref_inputs = kind.build(cfg, mix, inputs.derive_seeds(seed))
+    return srv, dict(ref_inputs, kind=kind)
 
 
 class Capture:
@@ -159,8 +199,10 @@ class Capture:
         return res
 
     def _local_work(self, orig, distinct, *a, **k):
+        import jax
+
         out = orig(distinct, *a, **k)
-        self.params.append({n: np.asarray(v) for n, v in out[0].items()})
+        self.params.append(jax.tree_util.tree_map(np.asarray, out[0]))
         self.rows.append(dict(zip(map(int, distinct), np.asarray(out[1], np.float64))))
         return out
 
@@ -191,6 +233,13 @@ def check_round_count(n_clients: int, m: int) -> int:
     configurations' fleets), so the angles and the plan are checked at about
     the fill the window runs at."""
     return 2 * math.ceil(n_clients / m)
+
+
+def rounds_checked(kind, cfg: dict, n_clients: int) -> int:
+    """The rounds the check covers: the kind's own count, or :func:`check_round_count`."""
+    m = cfg["train"]["m"]
+    own = getattr(kind, "check_round_count", None)
+    return own(cfg, n_clients, m) if own is not None else check_round_count(n_clients, m)
 
 
 def check_rounds(srv, k: int) -> Capture:
@@ -323,17 +372,34 @@ class MetricContext:
         self.trace = trace
         self.summary = trace_reduce.device_summary(trace)
         self.spans = trace_reduce.span_seconds(trace)
+        self.program = trace_reduce.program_spans(trace)
         self.records = records
         self.rounds = len(records)
         self.shapes = shapes
         self.peaks = peaks
 
-    def span_ms_per_round(self, name: str):
-        """Total seconds of a host span over the window's rounds, in ms per round."""
-        spans = self.spans.get(name)
-        if not spans or not self.rounds:
+    def _ms_per_round(self, seconds):
+        if not seconds or not self.rounds:
             return None
-        return sum(spans) / self.rounds * 1e3
+        return sum(seconds) / self.rounds * 1e3
+
+    def span_ms_per_round(self, name: str):
+        """Total seconds of a harness span over the window's rounds, in ms per round."""
+        return self._ms_per_round(self.spans.get(name))
+
+    def program_ms_per_round(self, name: str):
+        """Total seconds of a program span (``fl.*``) over the window's rounds,
+        in ms per round."""
+        return self._ms_per_round([s for s, _ in self.program.get(name, ())])
+
+    def arg_totals(self, name: str) -> dict:
+        """The numeric arguments of a program span, each summed over the
+        window's spans of that name: ``{key: sum}``."""
+        totals: dict = {}
+        for _, args in self.program.get(name, ()):
+            for key, value in args.items():
+                totals[key] = totals.get(key, 0) + value
+        return totals
 
     def device_seconds(self, fragment: str, *, modules: bool):
         """Summed device time of programs or operations named with ``fragment``."""
@@ -348,16 +414,15 @@ def load_peaks(kind: str) -> dict:
     return table[kind]
 
 
-def shapes_of(cfg: dict, srv) -> dict:
-    """The sizes the readers work operations and bytes out from."""
-    from counts import mlp_param_count
-
+def shapes_of(kind, cfg: dict, srv) -> dict:
+    """The sizes the readers work operations and bytes out from: the
+    protocol's and the store's, and the model kind's own."""
     store = getattr(srv.sampler, "gradient_store", None)
     return {
-        "n_params": mlp_param_count(inputs.mlp_dims(cfg)),
         "n_local_steps": cfg["train"]["n_local_steps"],
         "batch_size": cfg["train"]["batch_size"],
         "store": None if store is None else (store.n_clients, store.dim),
+        **kind.shapes(cfg, srv),
     }
 
 
@@ -394,9 +459,10 @@ def run_cell(bench: Bench, name: str, seed: int, seconds: float, trace: bool,
     limits = bench.limits(name)
     dev = device_info()
 
-    srv, ref_inputs = build_server(cfg, mix, seed)
-    rounds_checked = check_round_count(len(srv.dataset.clients), cfg["train"]["m"])
-    cap = check_rounds(srv, rounds_checked)
+    srv, ref_inputs = build_server(cfg, mix, seed, bench_dir=bench.dir)
+    kind = ref_inputs["kind"]
+    n_checked = rounds_checked(kind, cfg, len(srv.dataset.clients))
+    cap = check_rounds(srv, n_checked)
     warm_up(srv)
     setup_s = time.perf_counter() - t_start
     log(f"setup: {setup_s} s")
@@ -418,7 +484,7 @@ def run_cell(bench: Bench, name: str, seed: int, seconds: float, trace: bool,
 
             profile = ProfileData.from_file(trace_reduce.find_xplane(tdir))
             tr = trace_reduce.collect(profile, **TRACE_LINES)
-            ctx = MetricContext(tr, srv.history.records[-rounds:], shapes_of(cfg, srv),
+            ctx = MetricContext(tr, srv.history.records[-rounds:], shapes_of(kind, cfg, srv),
                                 load_peaks(dev["kind"]))
             values = {}
             for m in bench.metrics(name, "per_layer"):
@@ -439,9 +505,9 @@ def run_cell(bench: Bench, name: str, seed: int, seconds: float, trace: bool,
 
     t_check = time.perf_counter()
     prog = check.program_record(cap, ref_inputs["params0"])
-    ref = reference.replay(ref_inputs, rounds_checked)
+    ref = reference.replay(ref_inputs, n_checked)
     ok, table = check.verdict(check.numbers(prog, ref), limits)
-    log(f"check: {rounds_checked} rounds in {time.perf_counter() - t_check} s")
+    log(f"check: {n_checked} rounds in {time.perf_counter() - t_check} s")
     result["correct"] = ok
     result["metrics"] = {k: {"value": v, "unit": units[k]} for k, v in values.items()}
     result["device"] = dev

@@ -1,9 +1,10 @@
-"""A cell's inputs, made from its seed: every client's data and the initial weights.
+"""A cell's inputs, made from its seed: the seeds of each part and every client's data.
 
 The benchmark makes these itself, so the plain reference (``reference.py``)
-reads the same arrays without taking anything the program made. One
-``--seed`` gives one set of inputs; the sizes come from the configuration
-alone, so every seed does the same amount of work.
+reads the same arrays without taking anything the program made; the initial
+weights are the model kind's (``models/<kind>.py``), from the ``model``
+seed. One ``--seed`` gives one set of inputs; the sizes come from the
+configuration alone, so every seed does the same amount of work.
 
 Features are class-conditional Gaussians (``x ~ N(mu_y, noise^2 I)`` with
 ``mu_c ~ N(0, class_scale^2 I)``), drawn in one float32 block for the whole
@@ -75,29 +76,3 @@ def make_clients(data: dict, seed: int) -> list[tuple[np.ndarray, ...]]:
         clients.append((block[:n_train], lab[:n_train], block[n_train:], lab[n_train:]))
         start = stop
     return clients
-
-
-def mlp_dims(cfg: dict) -> tuple[int, ...]:
-    """(in, hidden..., out) of the configuration's MLP."""
-    return (int(cfg["data"]["dim"]), *map(int, cfg["train"]["hidden"]),
-            int(cfg["data"]["n_classes"]))
-
-
-def init_params(dims: tuple[int, ...], seed: int) -> dict:
-    """He-normal MLP weights and zero biases, made on the device in one jitted call.
-
-    Leaves are named as the program's MLP names them (``w0``, ``b0``, ...).
-    """
-    import jax
-    import jax.numpy as jnp
-
-    def init(key):
-        keys = jax.random.split(key, len(dims) - 1)
-        out = {}
-        for i, (d_in, d_out) in enumerate(zip(dims[:-1], dims[1:])):
-            w = jax.random.normal(keys[i], (d_in, d_out), jnp.float32)
-            out[f"w{i}"] = w * jnp.float32(np.sqrt(2.0 / d_in))
-            out[f"b{i}"] = jnp.zeros((d_out,), jnp.float32)
-        return out
-
-    return jax.jit(init)(jax.random.key(seed))

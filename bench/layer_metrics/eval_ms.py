@@ -1,7 +1,7 @@
 """Mean host time per round of the test-set evaluation
-(``FederatedServer.acc_fn``, its result blocked inside the span); the host-
-to-device copy of the test set is outside it, in the ``round`` span's self
-time: the ``eval`` span."""
+(``FederatedServer.acc_fn``, the compiled accuracy call over the test set
+that the server staged on the device when it was built, its result blocked
+inside the span); no round copies the test set: the ``eval`` span."""
 
 
 def read(ctx):

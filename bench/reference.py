@@ -1,4 +1,4 @@
-"""Plain reference of the served FL round for an MLP client model.
+"""Plain reference of the served FL round; the client model is the model kind's.
 
 Written from the paper's protocol (Fraboni et al., ICML 2021, Sec. 2-5),
 independent of the program:
@@ -15,100 +15,54 @@ independent of the program:
    space (:func:`algorithm2_plan`);
 2. draw ``l_1..l_m``: client ``l_k`` from urn ``k`` by one uniform per urn;
 3. every *distinct* drawn client runs ``N`` SGD steps of batch ``B`` from the
-   global model, on the softmax cross-entropy of the MLP (ReLU between dense
-   layers); batch rows are drawn per client, in ascending client order, from
-   the server's seeded generator;
+   global model, on the model kind's loss (``local_train``; for the MLP
+   kind, ``models/mlp.py``, the softmax cross-entropy of an MLP with ReLU
+   between dense layers); batch rows are drawn per client, in ascending
+   client order, from the server's seeded generator;
 4. the new global model is ``sum_i w_i theta_i + (1 - sum_i w_i) theta`` with
    ``w_i`` = (times drawn) / m (eq. 3/4); the round loss is the
    ``w``-weighted mean of the clients' mean step losses;
 5. the representative gradient of client ``i`` is ``theta_i - theta``,
-   flattened leaf by leaf in sorted leaf-name order (zero until it is first
-   drawn); the similarity is the angle between two of them (zero vectors: 0
-   to each other, pi/2 to the rest);
+   flattened leaf by leaf in ``jax.tree_util`` leaf order (sorted keys, for
+   a dict; zero until it is first drawn); the similarity is the angle
+   between two of them (zero vectors: 0 to each other, pi/2 to the rest);
 6. accuracy is the share of the global test set that the new model labels
-   right.
+   right (the kind's ``evaluate``).
 
 Exact ties (the angles among never-drawn clients are all 0) are broken by
 index, as the conventions in :func:`ward_merges` and :func:`cut` say, so
 that one set of angles gives one plan.
 
-Computation is jax.numpy at the configurations' precision, one client at a
-time: float32 with JAX's default matmul precision, which on the TPU is one
-bfloat16 pass, as the program's matmuls run; aggregation, angles and the
-plan are in float64 numpy,
-each round starting from the float32 rounding of the aggregate, as the
-program's float32 model does. ``mode="bf16"`` computes the clients' steps
-and the accuracy in bfloat16 (the control); ``mode="half_batch"`` trains
-on the first half of every batch and ``mode="frozen"`` never moves the
-global model (two faults).
+The model is the kind's and its weights a pytree. The kind's two functions
+compute in jax.numpy at the configuration's precision, one client at a
+time (for the MLP: float32 with JAX's default matmul precision, which on
+the TPU is one bfloat16 pass, as the program's matmuls run); aggregation,
+angles and the plan are in float64 numpy, each round starting from the
+float32 rounding of the aggregate, as the program's float32 model does.
+``mode="bf16"`` hands the kind ``bfloat16`` as its dtype for the clients'
+steps and the accuracy (the control); ``mode="half_batch"`` trains on the
+first half of every batch and ``mode="frozen"`` never moves the global
+model (two faults).
 """
 from __future__ import annotations
-
-import functools
 
 import numpy as np
 
 MODES = ("f32", "bf16", "half_batch", "frozen")
 
 
-def leaf_names(params: dict) -> list[str]:
-    return sorted(params)
-
-
-def flatten(params: dict) -> np.ndarray:
-    return np.concatenate([np.ravel(params[k]) for k in leaf_names(params)]).astype(np.float64)
-
-
-def _mlp(params, x, n_layers):
+def named_leaves(tree) -> dict:
+    """Leaf name -> leaf, in ``jax.tree_util`` leaf order, the order the
+    program's ``flatten_params`` concatenates in. A leaf is named by its
+    path's keys joined by ``/``: ``w0`` in a flat dict, ``body/0/w`` nested."""
     import jax
 
-    h = x
-    for i in range(n_layers):
-        h = h @ params[f"w{i}"] + params[f"b{i}"]
-        if i < n_layers - 1:
-            h = jax.nn.relu(h)
-    return h
+    leaves, _ = jax.tree_util.tree_flatten_with_path(tree)
+    return {jax.tree_util.keystr(path, simple=True, separator="/"): leaf for path, leaf in leaves}
 
 
-@functools.lru_cache(maxsize=None)
-def _local_sgd_fn(n_layers: int, dtype_name: str):
-    import jax
-    import jax.numpy as jnp
-
-    dtype = jnp.dtype(dtype_name)
-
-    def loss_fn(p, xb, yb):
-        logits = _mlp(p, xb, n_layers).astype(jnp.float32)
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        return -jnp.take_along_axis(logp, yb[:, None], axis=-1).mean()
-
-    def run(params, x, y, idx, lr):
-        p = {k: v.astype(dtype) for k, v in params.items()}
-        x = x.astype(dtype)
-
-        def step(p, rows):
-            loss, g = jax.value_and_grad(loss_fn)(p, x[rows], y[rows])
-            return {k: (p[k] - lr.astype(dtype) * g[k].astype(dtype)).astype(dtype)
-                    for k in p}, loss
-
-        p, losses = jax.lax.scan(step, p, idx)
-        return {k: v.astype(jnp.float32) for k, v in p.items()}, losses.mean()
-
-    return jax.jit(run)
-
-
-@functools.lru_cache(maxsize=None)
-def _accuracy_fn(n_layers: int, dtype_name: str):
-    import jax
-    import jax.numpy as jnp
-
-    dtype = jnp.dtype(dtype_name)
-
-    def acc(params, x, y):
-        p = {k: v.astype(dtype) for k, v in params.items()}
-        return (_mlp(p, x.astype(dtype), n_layers).argmax(-1) == y).mean(dtype=jnp.float32)
-
-    return jax.jit(acc)
+def flatten(tree) -> np.ndarray:
+    return np.concatenate([np.ravel(v) for v in named_leaves(tree).values()]).astype(np.float64)
 
 
 def arccos_distances(G: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
@@ -256,14 +210,19 @@ def replay(inputs: dict, rounds: int, *, mode: str = "f32") -> dict:
     """Run ``rounds`` rounds of the protocol from the cell's inputs.
 
     ``inputs``: ``clients`` (list of ``(x_train, y_train, x_test, y_test)``),
-    ``params0`` (host leaves), ``seeds`` (``sampler``, ``train``), ``rule``
-    (one of :data:`RULES`), ``m``, ``n_local_steps``, ``batch_size``, ``lr``.
+    ``params0`` (a pytree of host leaves), ``seeds`` (``sampler``,
+    ``train``), ``rule`` (one of :data:`RULES`), ``m``, ``n_local_steps``,
+    ``batch_size``, ``lr``, and ``kind``, the model kind whose
+    ``local_train(dtype)`` returns ``(params, x, y, (N, B) rows, lr) ->
+    (float32 params, mean step loss)`` and whose ``evaluate(dtype)`` returns
+    ``(params, x_test, y_test) -> accuracy``.
     Returns per round the plan drawn from, the drawn clients, the new global
     model, the loss, the accuracy and every distinct client's representative
     gradient; with Algorithm 2 also per round the angles over the clustered
     pool that the next round's plan was built from and the pool's gradient
     norms, and the gradient store after the last round.
     """
+    import jax
     import jax.numpy as jnp
 
     if mode not in MODES:
@@ -272,15 +231,15 @@ def replay(inputs: dict, rounds: int, *, mode: str = "f32") -> dict:
         raise ValueError(f"unknown plan rule {inputs['rule']!r}")
     dtype = "bfloat16" if mode == "bf16" else "float32"
     clients = inputs["clients"]
-    n_layers = len(inputs["params0"]) // 2
+    tree_map = jax.tree_util.tree_map
     n_train = np.array([c[1].size for c in clients])
     N, B, lr, m = inputs["n_local_steps"], inputs["batch_size"], inputs["lr"], inputs["m"]
     rng_s = np.random.default_rng(inputs["seeds"]["sampler"])
     rng_t = np.random.default_rng(inputs["seeds"]["train"])
-    sgd, acc_fn = _local_sgd_fn(n_layers, dtype), _accuracy_fn(n_layers, dtype)
+    sgd, acc_fn = inputs["kind"].local_train(dtype), inputs["kind"].evaluate(dtype)
     x_test = jnp.asarray(np.concatenate([c[2] for c in clients]))
     y_test = jnp.asarray(np.concatenate([c[3] for c in clients]))
-    theta = {k: np.asarray(v, np.float64) for k, v in inputs["params0"].items()}
+    theta = tree_map(lambda v: np.asarray(v, np.float64), inputs["params0"])
     store = inputs["rule"] == "algorithm2"
     G = np.zeros((len(clients), flatten(theta).size))
     plan_r = (algorithm2_plan(G, n_train, m)[0] if store
@@ -291,9 +250,9 @@ def replay(inputs: dict, rounds: int, *, mode: str = "f32") -> dict:
         drawn = draw(plan_r, rng_s)
         distinct, counts = np.unique(drawn, return_counts=True)
         w = counts / m
-        start = {k: jnp.asarray(v, jnp.float32) for k, v in theta.items()}
-        base = {k: np.asarray(v, np.float64) for k, v in start.items()}
-        new = {k: (1.0 - w.sum()) * v for k, v in theta.items()}
+        start = tree_map(lambda v: jnp.asarray(v, jnp.float32), theta)
+        base = tree_map(lambda v: np.asarray(v, np.float64), start)
+        new = tree_map(lambda v: (1.0 - w.sum()) * v, theta)
         losses, rows = [], {}
         for i, wi in zip(distinct, w):
             x, y = clients[i][0], clients[i][1]
@@ -302,11 +261,10 @@ def replay(inputs: dict, rounds: int, *, mode: str = "f32") -> dict:
                 idx = idx[:, : B // 2]
             p, loss = sgd(start, jnp.asarray(x), jnp.asarray(y), jnp.asarray(idx, jnp.int32),
                           jnp.float32(lr))
-            p = {k: np.asarray(v, np.float64) for k, v in p.items()}
+            p = tree_map(lambda v: np.asarray(v, np.float64), p)
             losses.append(float(loss))
-            for k in new:
-                new[k] = new[k] + wi * p[k]
-            rows[int(i)] = flatten({k: p[k] - base[k] for k in p})
+            new = tree_map(lambda a, b: a + wi * b, new, p)
+            rows[int(i)] = flatten(tree_map(np.subtract, p, base))
             G[i] = rows[int(i)]
         if mode != "frozen":
             theta = new
@@ -315,7 +273,7 @@ def replay(inputs: dict, rounds: int, *, mode: str = "f32") -> dict:
         out["rows"].append(rows)
         out["params"].append(theta)
         out["loss"].append(float(np.average(losses, weights=w)))
-        cur = {k: jnp.asarray(v, jnp.float32) for k, v in theta.items()}
+        cur = tree_map(lambda v: jnp.asarray(v, jnp.float32), theta)
         out["acc"].append(float(acc_fn(cur, x_test, y_test)))
         if store:
             plan_r, dist, norms = algorithm2_plan(G, n_train, m)

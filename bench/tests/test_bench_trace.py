@@ -45,11 +45,16 @@ def test_reduction_of_a_trace_recorded_on_cpu(tmp_path):
                 with profiler.TraceAnnotation("draw"):
                     time.sleep(0.03)  # the host works, the device waits
                 with profiler.TraceAnnotation("local_work"):
-                    f(x).block_until_ready()
+                    with profiler.TraceAnnotation("fl.local_work", slots=4, share=0.5, tag="x"):
+                        f(x).block_until_ready()
     profiler.stop_trace()
     trace = tr.collect(profiler.ProfileData.from_file(tr.find_xplane(str(tmp_path))),
                        **ft.CPU_TRACE)
     assert sorted({n for n, _, _ in trace.spans}) == ["draw", "local_work", "round", "window"]
+    # the program's spans are kept apart, with their numeric arguments only
+    assert [(n, args) for n, _, _, args in trace.program] == [
+        ("fl.local_work", {"slots": 4, "share": 0.5})] * 3
+    assert [len(v) for v in tr.program_spans(trace).values()] == [3]
     s = tr.device_summary(trace)
     assert 0.09 <= s["window_s"] < 5.0
     assert 0.0 < s["busy_s"] < s["window_s"]

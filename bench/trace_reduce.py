@@ -4,7 +4,9 @@ The trace is the ``.xplane.pb`` that ``jax.profiler`` writes; it is read with
 ``jax.profiler.ProfileData``. Device operations are the events of each device
 plane's ``XLA Ops`` line, programs those of its ``XLA Modules`` line. Host
 spans are the ``TraceAnnotation`` events the harness wraps around the
-server's phases; they share the device trace's clock.
+server's phases; program spans are the program's own ``fl.*`` events, kept
+apart with their numeric arguments (the program's counters). All share the
+device trace's clock.
 
 Busy time is the union of one device's operation intervals inside the
 window; the idle share is one minus busy over the window. Each idle gap is
@@ -20,6 +22,8 @@ import os
 #: Host spans the harness records, outermost first. ``window`` encloses the
 #: whole traced window and never labels a gap.
 SPAN_NAMES = ("window", "round", "draw", "local_work", "observe", "eval")
+#: The prefix of the program's own spans (``repro.fl``'s ``TraceAnnotation`` names).
+PROGRAM_PREFIX = "fl."
 
 
 def op_name(raw: str) -> str:
@@ -36,6 +40,7 @@ class Trace:
     ops: dict  # device plane name -> [(op name, start, end)]
     modules: dict  # device plane name -> [(program name, start, end)]
     spans: list  # [(span name, start, end)]
+    program: list = dataclasses.field(default_factory=list)  # [(name, start, end, {arg: number})]
 
     def window(self) -> tuple[float, float]:
         """The ``window`` span, or the extent of all spans without one."""
@@ -56,13 +61,14 @@ def find_xplane(log_dir: str) -> str:
 
 def collect(profile, *, device_prefix: str = "/device:", op_line: str = "XLA Ops",
             module_line: str = "XLA Modules", span_names=SPAN_NAMES) -> Trace:
-    """Pull device operations, programs and harness spans out of a ``ProfileData``.
+    """Pull device operations, programs, harness spans and the program's
+    ``fl.*`` spans out of a ``ProfileData``.
 
     ``device_prefix`` and ``op_line`` select which planes and lines count as
     the device; ``op_line`` may also be a predicate on the line's name, which
     a test on the CPU points at the host's executor threads.
     """
-    ops, modules, spans = {}, {}, []
+    ops, modules, spans, program = {}, {}, [], []
     wanted = set(span_names)
     is_op = op_line if callable(op_line) else (lambda name: name == op_line)
     for plane in profile.planes:
@@ -75,12 +81,13 @@ def collect(profile, *, device_prefix: str = "/device:", op_line: str = "XLA Ops
                     for e in line.events
                 )
             elif plane.name.startswith("/host"):
-                spans.extend(
-                    (e.name, e.start_ns, e.start_ns + e.duration_ns)
-                    for e in line.events
-                    if e.name in wanted
-                )
-    return Trace(ops=ops, modules=modules, spans=spans)
+                for e in line.events:
+                    if e.name in wanted:
+                        spans.append((e.name, e.start_ns, e.start_ns + e.duration_ns))
+                    elif e.name.startswith(PROGRAM_PREFIX):
+                        args = {k: v for k, v in e.stats if isinstance(v, (int, float))}
+                        program.append((e.name, e.start_ns, e.start_ns + e.duration_ns, args))
+    return Trace(ops=ops, modules=modules, spans=spans, program=program)
 
 
 def merge(intervals, lo: float, hi: float) -> list[tuple[float, float]]:
@@ -153,6 +160,16 @@ def span_seconds(trace: Trace) -> dict:
     for name, s, e in trace.spans:
         if name != "window" and s >= lo and e <= hi:
             out[name].append((e - s) * 1e-9)
+    return dict(out)
+
+
+def program_spans(trace: Trace) -> dict:
+    """Program span name -> ``[(seconds, {arg: number})]``, inside the window."""
+    lo, hi = trace.window()
+    out: dict = collections.defaultdict(list)
+    for name, s, e, args in trace.program:
+        if s >= lo and e <= hi:
+            out[name].append(((e - s) * 1e-9, args))
     return dict(out)
 
 
