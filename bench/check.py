@@ -10,12 +10,13 @@ Each number is a reading of one guarantee over the checked rounds:
 * ``loss_rel``: the largest relative gap of a round's train loss;
 * ``update_gap``: the first round's change of the global model, by the worst
   leaf: ``| |d_prog| - |d_ref| |`` over ``max(|d_ref|, median leaf |d_ref|)``,
-  leaves named by their pytree path (:func:`reference.named_leaves`);
+  leaves named by their pytree path (:func:`reference.named_leaves`); each
+  side records only these per-leaf norms (:func:`reference.leaf_norms`);
 * ``change_gap``: the same for the change over all checked rounds;
 * ``acc_gap``: the largest gap of a round's test accuracy;
-* ``rows_rel``: the largest relative distance of a client's representative
-  gradient (``theta_i - theta``, the engine's per-client output) from the
-  reference's;
+* ``rows_rel`` (where the program's rounds return rows): the largest
+  relative distance of a client's representative gradient (``theta_i -
+  theta``, the engine's per-client output) from the reference's;
 * ``store_rel`` (plans built from the gradient store): the same for the rows
   of the store after the last checked round;
 * ``dist_abs`` (same): the largest gap, in radians, of an angle the plan
@@ -35,13 +36,11 @@ import math
 
 import numpy as np
 
-from reference import named_leaves
 
-
-def _leaf_gap(prog_delta: dict, ref_delta: dict) -> float:
-    names = list(ref_delta)
-    ref = np.array([np.linalg.norm(ref_delta[k]) for k in names])
-    got = np.array([np.linalg.norm(np.asarray(prog_delta[k], np.float64)) for k in names])
+def _leaf_gap(prog_norms: dict, ref_norms: dict) -> float:
+    names = list(ref_norms)
+    ref = np.array([ref_norms[k] for k in names])
+    got = np.array([prog_norms[k] for k in names])
     median = float(np.median(ref))
     keep = ref >= 1e-3 * median
     if not keep.any():
@@ -55,13 +54,27 @@ def _sound(norms: np.ndarray) -> np.ndarray:
     return norms >= 1e-3 * float(np.median(live)) if live.size else norms > 0
 
 
-def _rows_rel(got: np.ndarray, want: np.ndarray) -> float:
-    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
-    norms = np.linalg.norm(want, axis=1)
+def _row_norm(row: np.ndarray) -> float:
+    """``np.linalg.norm(rows, axis=1)``'s reading of one row, taken alone."""
+    return np.linalg.norm(row[None], axis=1)[0]
+
+
+def _gap_norm(got: np.ndarray, want: np.ndarray) -> float:
+    """:func:`_row_norm` of ``got - want`` (``got`` widened to float64),
+    squared in place: the same products and sums, one temporary row."""
+    diff = np.subtract(got, want, dtype=np.float64)
+    return np.sqrt(np.add.reduce(np.square(diff, out=diff)[None], axis=1))[0]
+
+
+def _rows_rel(got, want) -> float:
+    """Over rows (float64 ``want``, ``got`` in any float dtype, widened),
+    one pair at a time, so no second copy of all the rows is made."""
+    norms = np.array([_row_norm(np.asarray(w, np.float64)) for w in want])
+    gaps = np.array([_gap_norm(g, w) for g, w in zip(got, want)])
     keep = _sound(norms)
     if not keep.any():
         return math.inf
-    return float((np.linalg.norm(got - want, axis=1)[keep] / norms[keep]).max())
+    return float((gaps[keep] / norms[keep]).max())
 
 
 def numbers(prog: dict, ref: dict) -> dict:
@@ -70,27 +83,20 @@ def numbers(prog: dict, ref: dict) -> dict:
     k = len(ref["loss"])
     if len(prog["loss"]) != k:
         return {"rounds_missing": float(k - len(prog["loss"]))}
-
-    def delta(side, a, b):
-        start, end = named_leaves(side["params"][a]), named_leaves(side["params"][b])
-        return {n: np.asarray(end[n], np.float64) - np.asarray(v, np.float64)
-                for n, v in start.items()}
-
     out = {
         "draw_mismatch": float(sum(int((np.asarray(a) != b).sum())
                                    for a, b in zip(prog["clients"], ref["clients"]))),
         "plan_gap": max(float(np.abs(np.asarray(a, np.float64) - b).max())
                         for a, b in zip(prog["plans"], ref["plans"])),
         "loss_rel": max(abs(a - b) / abs(b) for a, b in zip(prog["loss"], ref["loss"])),
-        "update_gap": _leaf_gap(delta(prog, 0, 1), delta(ref, 0, 1)),
-        "change_gap": _leaf_gap(delta(prog, 0, k), delta(ref, 0, k)),
+        "update_gap": _leaf_gap(prog["update_norms"], ref["update_norms"]),
+        "change_gap": _leaf_gap(prog["change_norms"], ref["change_norms"]),
         "acc_gap": max(abs(a - b) for a, b in zip(prog["acc"], ref["acc"])),
-        "rows_rel": max(
-            _rows_rel([got[i] for i in want], list(want.values())) if set(got) == set(want)
-            else math.inf
-            for got, want in zip(prog["rows"], ref["rows"])
-        ),
     }
+    if prog["rows"]:
+        gaps = [_rows_rel([got[i] for i in want], list(want.values())) if set(got) == set(want)
+                else math.inf for got, want in zip(prog["rows"], ref["rows"])]
+        out["rows_rel"] = max(gaps) if len(prog["rows"]) == len(ref["rows"]) == k else math.inf
     if ref["store"]:
         if not prog["store"] or len(prog["dist"]) != len(ref["dist"]):
             return dict(out, store_rel=math.inf, dist_abs=math.inf)
@@ -115,12 +121,13 @@ def verdict(values: dict, limits: dict) -> tuple[bool, dict]:
     return ok, table
 
 
-def program_record(capture, params0: dict) -> dict:
+def program_record(capture) -> dict:
     """The capture of the program's checked rounds, in :func:`reference.replay`'s shape."""
     return {
         "clients": capture.clients,
         "plans": capture.plans,
-        "params": [params0] + capture.params,
+        "update_norms": capture.update_norms,
+        "change_norms": capture.change_norms,
         "loss": capture.loss,
         "acc": capture.acc,
         "rows": capture.rows,

@@ -42,17 +42,18 @@ import reference  # noqa: E402
 
 
 def stand_ins(prog: dict, ref_inputs: dict, rounds: int) -> dict:
-    """Records of what stands in the program's place, keyed by name."""
+    """Records of what stands in the program's place, keyed by name; the
+    reference's stand-ins keep rows where the program's record has them."""
     out = {}
     for mode, name in (("bf16", "control"), ("half_batch", "half_batch"),
                        ("frozen", "state_unchanged")):
-        out[name] = reference.replay(ref_inputs, rounds, mode=mode)
+        out[name] = reference.replay(ref_inputs, rounds, mode=mode, rows=bool(prog["rows"]))
     n = len(ref_inputs["clients"])
     drawn = [c.copy() for c in prog["clients"]]
     drawn[0][0] = (drawn[0][0] + 1) % n
     out["draw_altered"] = dict(prog, clients=drawn)
     plan = np.array(prog["plans"][-1], np.float64)
-    token = 1.0 / sum(c[1].size for c in ref_inputs["clients"])
+    token = 1.0 / sum(len(c[1]) for c in ref_inputs["clients"])
     src = int(np.argmax(plan[0]))
     plan[0, src] -= token
     plan[0, (src + 1) % n] += token
@@ -68,12 +69,12 @@ def readings(bench, cell_name: str, seeds, control_seeds) -> dict:
         t0 = time.perf_counter()
         srv, ref_inputs = harness.build_server(cfg, mix, seed, bench_dir=bench.dir)
         rounds = harness.rounds_checked(ref_inputs["kind"], cfg, len(srv.dataset.clients))
-        cap = harness.check_rounds(srv, rounds)
+        cap = harness.check_rounds(srv, rounds, ref_inputs["params0"])
         srv.close()
         del srv
         gc.collect()
-        prog = check.program_record(cap, ref_inputs["params0"])
-        ref = reference.replay(ref_inputs, rounds)
+        prog = check.program_record(cap)
+        ref = reference.replay(ref_inputs, rounds, rows=bool(prog["rows"]))
         row = {"program": check.numbers(prog, ref)}
         if seed in control_seeds:
             for name, rec in stand_ins(prog, ref_inputs, rounds).items():

@@ -16,8 +16,9 @@ A run:
    server, by the program's ``build_experiment``; its first
    :func:`rounds_checked` rounds
    through ``FederatedServer.run``, recorded for the check; a warm-up of every shape
-   the window can meet (one local-work dispatch, update slice and store
-   scatter per distinct-client count ``1..m``);
+   the window can meet (one local-work dispatch per distinct-client count
+   ``1..m``, with its update slice and store scatter where the round
+   returns rows);
 2. the window: the same server's ``run`` until ``seconds`` have passed, stopped
    by its ``should_stop`` hook, one stamp per round, ``block_until_ready`` on
    the global model before the clock is read; with ``trace`` the window runs
@@ -174,13 +175,21 @@ def build_server(cfg: dict, mix: dict, seed: int, *, bench_dir: Path = BENCH_DIR
 
 class Capture:
     """Records what the server's first rounds produce, through wrappers on
-    the built instances' attributes; :meth:`close` removes them."""
+    the built instances' attributes; :meth:`close` removes them.
 
-    def __init__(self, srv):
+    The global model is copied to the host twice, after the first round and
+    at :meth:`close`, and kept only as the per-leaf norms of its change from
+    ``params0`` (the initial weights, on the host). A round's rows are kept,
+    in the dtype the engine returns them in, where the round returns them."""
+
+    def __init__(self, srv, params0):
         self.srv = srv
-        self.clients, self.plans, self.params, self.rows = [], [], [], []
+        self.params0 = reference.named_leaves(params0)
+        self.clients, self.plans, self.rows = [], [], []
         self.loss, self.acc, self.dist = [], [], []
+        self.update_norms = self.change_norms = None
         self.store = None  # the gradient store after the last round, on the host
+        self._model = None  # the last round's global model, on the device
         self._undo = []
         self._wrap(srv.sampler, "sample", self._sample)
         self._wrap(srv, "_phase_local_work", self._local_work)
@@ -199,12 +208,16 @@ class Capture:
         return res
 
     def _local_work(self, orig, distinct, *a, **k):
-        import jax
-
         out = orig(distinct, *a, **k)
-        self.params.append(jax.tree_util.tree_map(np.asarray, out[0]))
-        self.rows.append(dict(zip(map(int, distinct), np.asarray(out[1], np.float64))))
+        if self._model is None:
+            self.update_norms = self._norms(out[0])
+        self._model = out[0]
+        if out[1] is not None:
+            self.rows.append(dict(zip(map(int, distinct), np.asarray(out[1]))))
         return out
+
+    def _norms(self, model) -> dict:
+        return reference.leaf_norms(reference.named_leaves(model), self.params0)
 
     def _distances(self, orig, G, measure):
         out = orig(G, measure)
@@ -216,6 +229,9 @@ class Capture:
         self.acc.append(rec.test_acc)
 
     def close(self) -> None:
+        if self._model is not None:
+            self.change_norms = self._norms(self._model)
+            self._model = None
         store = getattr(self.srv.sampler, "gradient_store", None)
         if store is not None:
             self.store = store.asnumpy()
@@ -242,9 +258,10 @@ def rounds_checked(kind, cfg: dict, n_clients: int) -> int:
     return own(cfg, n_clients, m) if own is not None else check_round_count(n_clients, m)
 
 
-def check_rounds(srv, k: int) -> Capture:
-    """Drive the server's first ``k`` rounds through its ``run``, recorded."""
-    cap = Capture(srv)
+def check_rounds(srv, k: int, params0) -> Capture:
+    """Drive the server's first ``k`` rounds through its ``run``, recorded;
+    ``params0`` is the model the server starts from, on the host."""
+    cap = Capture(srv, params0)
     srv.run(on_round=cap.on_round, should_stop=lambda: len(cap.loss) >= k)
     cap.close()
     return cap
@@ -252,15 +269,18 @@ def check_rounds(srv, k: int) -> Capture:
 
 def warm_up(srv) -> None:
     """Compile what a round with ``c`` distinct clients runs, for every
-    ``c = 1..m``: the local-work dispatch with its ``updates[:c]`` slice, the
-    boolean gather of the kept rows and the store scatter. The server's model
-    and store are left as they were."""
+    ``c = 1..m``: the local-work dispatch and, where the round returns rows,
+    its ``updates[:c]`` slice, the boolean gather of the kept rows and the
+    store scatter. The server's model and store are left as they were."""
     import jax
 
     store = getattr(srv.sampler, "gradient_store", None)
     for c in range(1, srv.sampler.m + 1):
         ids = np.arange(c)
-        _, updates, _ = srv._phase_local_work(ids, np.full(c, 1.0 / c), 0.0)
+        updates, losses = srv._phase_local_work(ids, np.full(c, 1.0 / c), 0.0)[1:]
+        if updates is None:
+            jax.block_until_ready(losses)
+            continue
         kept = updates[np.ones(c, bool)]
         if store is not None:
             before = store.snapshot()
@@ -462,7 +482,7 @@ def run_cell(bench: Bench, name: str, seed: int, seconds: float, trace: bool,
     srv, ref_inputs = build_server(cfg, mix, seed, bench_dir=bench.dir)
     kind = ref_inputs["kind"]
     n_checked = rounds_checked(kind, cfg, len(srv.dataset.clients))
-    cap = check_rounds(srv, n_checked)
+    cap = check_rounds(srv, n_checked, ref_inputs["params0"])
     warm_up(srv)
     setup_s = time.perf_counter() - t_start
     log(f"setup: {setup_s} s")
@@ -504,8 +524,8 @@ def run_cell(bench: Bench, name: str, seed: int, seconds: float, trace: bool,
     gc.collect()
 
     t_check = time.perf_counter()
-    prog = check.program_record(cap, ref_inputs["params0"])
-    ref = reference.replay(ref_inputs, n_checked)
+    prog = check.program_record(cap)
+    ref = reference.replay(ref_inputs, n_checked, rows=bool(prog["rows"]))
     ok, table = check.verdict(check.numbers(prog, ref), limits)
     log(f"check: {n_checked} rounds in {time.perf_counter() - t_check} s")
     result["correct"] = ok

@@ -61,10 +61,6 @@ def named_leaves(tree) -> dict:
     return {jax.tree_util.keystr(path, simple=True, separator="/"): leaf for path, leaf in leaves}
 
 
-def flatten(tree) -> np.ndarray:
-    return np.concatenate([np.ravel(v) for v in named_leaves(tree).values()]).astype(np.float64)
-
-
 def arccos_distances(G: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
     """(n, d) -> ((n, n) angles, (n,) norms), in float64."""
     G = np.asarray(G, np.float64)
@@ -206,21 +202,51 @@ def draw(plan_r: np.ndarray, rng: np.random.Generator) -> np.ndarray:
     return out
 
 
-def replay(inputs: dict, rounds: int, *, mode: str = "f32") -> dict:
+#: Elements per block of the in-place aggregation, so that its temporaries stay small.
+_BLOCK = 1 << 18
+
+
+def _add_scaled(acc: np.ndarray, w: float, leaf) -> None:
+    """``acc += w * float64(leaf)`` in place, a block of the leading axis at
+    a time: the same operation on every element as on the whole leaf, with
+    temporaries of about ``_BLOCK`` elements. Slices of the leading axis are
+    views whatever the memory layout (host copies of device arrays need not
+    be C-ordered), so nothing is written to a copy."""
+    b = np.asarray(leaf)
+    if acc.ndim == 0:
+        acc += w * b.astype(np.float64)
+        return
+    step = max(1, _BLOCK * acc.shape[0] // max(acc.size, 1))
+    for s in range(0, acc.shape[0], step):
+        acc[s:s + step] += w * b[s:s + step].astype(np.float64)
+
+
+def leaf_norms(end: dict, start: dict) -> dict:
+    """Leaf name -> the norm of ``end - start``, the difference taken in float64."""
+    return {n: float(np.linalg.norm(np.subtract(np.asarray(end[n]), v, dtype=np.float64)))
+            for n, v in start.items()}
+
+
+def replay(inputs: dict, rounds: int, *, mode: str = "f32", rows: bool = False) -> dict:
     """Run ``rounds`` rounds of the protocol from the cell's inputs.
 
-    ``inputs``: ``clients`` (list of ``(x_train, y_train, x_test, y_test)``),
-    ``params0`` (a pytree of host leaves), ``seeds`` (``sampler``,
-    ``train``), ``rule`` (one of :data:`RULES`), ``m``, ``n_local_steps``,
-    ``batch_size``, ``lr``, and ``kind``, the model kind whose
-    ``local_train(dtype)`` returns ``(params, x, y, (N, B) rows, lr) ->
-    (float32 params, mean step loss)`` and whose ``evaluate(dtype)`` returns
-    ``(params, x_test, y_test) -> accuracy``.
-    Returns per round the plan drawn from, the drawn clients, the new global
-    model, the loss, the accuracy and every distinct client's representative
-    gradient; with Algorithm 2 also per round the angles over the clustered
-    pool that the next round's plan was built from and the pool's gradient
-    norms, and the gradient store after the last round.
+    ``inputs``: ``clients`` (list of ``(x_train, y_train, x_test, y_test)``,
+    a client's samples along the leading axis), ``params0`` (a pytree of
+    float32 host leaves), ``seeds`` (``sampler``, ``train``), ``rule`` (one
+    of :data:`RULES`), ``m``, ``n_local_steps``, ``batch_size``, ``lr``, and
+    ``kind``, the model kind whose ``local_train(dtype)`` returns ``(params,
+    x, y, (N, B) rows, lr) -> (float32 params, mean step loss)`` and whose
+    ``evaluate(dtype)`` returns ``(params, x_test, y_test) -> accuracy``.
+
+    Returns per round the plan drawn from, the drawn clients, the loss and
+    the accuracy; per leaf the norms of the global model's change after the
+    first round (``update_norms``) and after the last (``change_norms``), by
+    :func:`leaf_norms`; with ``rows``, per round every distinct client's
+    representative gradient. With Algorithm 2 also per round the angles over
+    the clustered pool that the next round's plan was built from and the
+    pool's gradient norms, and the gradient store after the last round. Host
+    memory is a few copies of the model, whatever ``rounds`` and the number
+    of clients, unless a store or the rows are kept.
     """
     import jax
     import jax.numpy as jnp
@@ -231,29 +257,38 @@ def replay(inputs: dict, rounds: int, *, mode: str = "f32") -> dict:
         raise ValueError(f"unknown plan rule {inputs['rule']!r}")
     dtype = "bfloat16" if mode == "bf16" else "float32"
     clients = inputs["clients"]
-    tree_map = jax.tree_util.tree_map
-    n_train = np.array([c[1].size for c in clients])
+    n_train = np.array([len(c[1]) for c in clients])
     N, B, lr, m = inputs["n_local_steps"], inputs["batch_size"], inputs["lr"], inputs["m"]
     rng_s = np.random.default_rng(inputs["seeds"]["sampler"])
     rng_t = np.random.default_rng(inputs["seeds"]["train"])
     sgd, acc_fn = inputs["kind"].local_train(dtype), inputs["kind"].evaluate(dtype)
     x_test = jnp.asarray(np.concatenate([c[2] for c in clients]))
     y_test = jnp.asarray(np.concatenate([c[3] for c in clients]))
-    theta = tree_map(lambda v: np.asarray(v, np.float64), inputs["params0"])
+    params0 = named_leaves(inputs["params0"])
+    treedef = jax.tree_util.tree_structure(inputs["params0"])
+    theta = {n: np.asarray(v, np.float64) for n, v in params0.items()}
+
+    def float32(tree: dict):
+        return jax.tree_util.tree_unflatten(
+            treedef, [jnp.asarray(v, jnp.float32) for v in tree.values()])
+
+    start = float32(theta)
+    d = sum(v.size for v in theta.values())
     store = inputs["rule"] == "algorithm2"
-    G = np.zeros((len(clients), flatten(theta).size))
+    G = np.zeros((len(clients), d)) if store else None
     plan_r = (algorithm2_plan(G, n_train, m)[0] if store
               else np.tile(n_train / n_train.sum(), (m, 1)))
-    out = {"plans": [], "clients": [], "params": [theta], "loss": [], "acc": [], "rows": [],
-           "store": [], "dist": [], "pool_norms": []}
-    for _ in range(rounds):
+    out = {"plans": [], "clients": [], "loss": [], "acc": [], "rows": [], "store": [],
+           "dist": [], "pool_norms": []}
+    for t in range(rounds):
         drawn = draw(plan_r, rng_s)
         distinct, counts = np.unique(drawn, return_counts=True)
         w = counts / m
-        start = tree_map(lambda v: jnp.asarray(v, jnp.float32), theta)
-        base = tree_map(lambda v: np.asarray(v, np.float64), start)
-        new = tree_map(lambda v: (1.0 - w.sum()) * v, theta)
-        losses, rows = [], {}
+        if mode != "frozen":
+            for v in theta.values():
+                v *= 1.0 - w.sum()
+        base = named_leaves(jax.tree_util.tree_map(np.asarray, start)) if store or rows else None
+        losses, round_rows = [], {}
         for i, wi in zip(distinct, w):
             x, y = clients[i][0], clients[i][1]
             idx = rng_t.integers(0, n_train[i], size=(N, B))
@@ -261,24 +296,35 @@ def replay(inputs: dict, rounds: int, *, mode: str = "f32") -> dict:
                 idx = idx[:, : B // 2]
             p, loss = sgd(start, jnp.asarray(x), jnp.asarray(y), jnp.asarray(idx, jnp.int32),
                           jnp.float32(lr))
-            p = tree_map(lambda v: np.asarray(v, np.float64), p)
             losses.append(float(loss))
-            new = tree_map(lambda a, b: a + wi * b, new, p)
-            rows[int(i)] = flatten(tree_map(np.subtract, p, base))
-            G[i] = rows[int(i)]
-        if mode != "frozen":
-            theta = new
+            row, off = (np.empty(d) if base is not None else None), 0
+            for name, leaf in named_leaves(p).items():
+                leaf = np.asarray(leaf)
+                if mode != "frozen":
+                    _add_scaled(theta[name], wi, leaf)
+                if row is not None:
+                    np.subtract(leaf.ravel(), base[name].ravel(), out=row[off:off + leaf.size],
+                                dtype=np.float64)
+                off += leaf.size
+            if rows:
+                round_rows[int(i)] = row
+            if store:
+                G[i] = row
+        del p, base  # freed before the next round makes its own
+        start = float32(theta)
+        if t == 0:
+            out["update_norms"] = leaf_norms(theta, params0)
         out["plans"].append(plan_r)
         out["clients"].append(drawn)
-        out["rows"].append(rows)
-        out["params"].append(theta)
+        if rows:
+            out["rows"].append(round_rows)
         out["loss"].append(float(np.average(losses, weights=w)))
-        cur = tree_map(lambda v: jnp.asarray(v, jnp.float32), theta)
-        out["acc"].append(float(acc_fn(cur, x_test, y_test)))
+        out["acc"].append(float(acc_fn(start, x_test, y_test)))
         if store:
             plan_r, dist, norms = algorithm2_plan(G, n_train, m)
             out["dist"].append(dist)
             out["pool_norms"].append(norms)
+    out["change_norms"] = leaf_norms(theta, params0)
     if store:
         out["store"].append(G)
     return out
