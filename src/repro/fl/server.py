@@ -45,16 +45,19 @@ Tracing: every round is a ``jax.profiler.StepTraceAnnotation`` named
 ``fl.round`` (``step_num`` = the round), and each phase above a
 ``TraceAnnotation`` inside it: ``fl.availability``, ``fl.draw``,
 ``fl.resolve``, ``fl.local_work`` (the batched engine adds
-``fl.local_work.prep`` / ``.dispatch`` / ``.wait``), ``fl.observe``,
-``fl.eval`` (with ``fl.eval.run``, the compiled accuracy call) and
-``fl.record``. The test set is copied to the device once, when the server
-is built, under ``fl.eval.stage`` outside any round; no round copies it.
+``fl.local_work.prep`` / ``.dispatch`` / ``.wait``), ``fl.observe``
+(with ``fl.observe.gather`` only in a round where someone dropped or was
+late: the kept rows' gather; when every participant contributed, the
+engine's rows go to the sampler as they are), ``fl.eval`` (with
+``fl.eval.run``, the compiled accuracy call) and ``fl.record``. The test
+set is copied to the device once, when the server is built, under
+``fl.eval.stage`` outside any round; no round copies it.
 The spans land in a profiler trace on the device trace's clock when one is
 active (``jax.profiler.trace``) and cost about a microsecond each
 otherwise; none blocks or moves data. Counters ride as arguments:
 ``bytes`` (host-to-device bytes) on ``fl.eval.stage`` and
 ``fl.local_work.dispatch``, ``distinct`` and ``slots`` on
-``fl.local_work.prep``.
+``fl.local_work.prep``, ``rows`` (the kept rows) on ``fl.observe.gather``.
 """
 from __future__ import annotations
 
@@ -405,9 +408,13 @@ class FederatedServer:
             keep = ~(dropped | late)
             contributing = distinct[keep]
             if contributing.size:
-                self.sampler.observe_updates(
-                    contributing, updates_flat[np.asarray(keep)]
-                )
+                # when everyone contributed, the engine's rows are the kept
+                # rows: they go over as they are (no gather, copy or transfer)
+                rows = updates_flat
+                if not keep.all():
+                    with TraceAnnotation("fl.observe.gather", rows=int(contributing.size)):
+                        rows = updates_flat[np.asarray(keep)]
+                self.sampler.observe_updates(contributing, rows)
 
             # rebuild-cost telemetry is read *after* observe_updates: the
             # drift statistic (and any sync rebuild) happens there
