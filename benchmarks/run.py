@@ -17,14 +17,7 @@ Prints ``name,us_per_call,derived`` CSV rows:
   fig2_dirichlet       — Figure 2 (Dirichlet-α heterogeneity sweep)
   table_variance       — Section 3.2 / Appendix B statistics (theory vs MC)
   ablations            — Appendix D.2/D.4/D.5
-  bench_sampler_cost   — Theorems 3/4 complexity scaling
-  bench_kernels        — Pallas kernel paths + oracles
-  bench_fl_collectives — communication accounting (paper's motivation)
-  bench_round_engine   — batched on-device round engine vs compat loop
-  bench_engine_sharded — mesh-sharded engine: per-device staged bytes sweep
-  bench_async_planner  — async re-clustering planner + streamed similarity
-  bench_store_scale    — sketched GradientStore: bytes/scatter/rebuild at scale
-  bench_scheduler      — round schedulers (sync/deadline/overselect) under churn
+  beyond_paper         — extensions: stale-aware Algorithm 2, device similarity, churn
   scheme_race          — every registered selection scheme raced on one sweep
 """
 from __future__ import annotations
@@ -36,15 +29,6 @@ import traceback
 
 from benchmarks import (
     ablations,
-    bench_async_planner,
-    bench_dryrun_roofline,
-    bench_engine_sharded,
-    bench_fl_collectives,
-    bench_kernels,
-    bench_round_engine,
-    bench_sampler_cost,
-    bench_scheduler,
-    bench_store_scale,
     beyond_paper,
     fig1_controlled,
     fig2_dirichlet,
@@ -55,15 +39,6 @@ from repro.launch.compile_cache import enable_compile_cache
 
 MODULES = [
     ("table_variance", table_variance),
-    ("bench_sampler_cost", bench_sampler_cost),
-    ("bench_round_engine", bench_round_engine),
-    ("bench_engine_sharded", bench_engine_sharded),
-    ("bench_async_planner", bench_async_planner),
-    ("bench_store_scale", bench_store_scale),
-    ("bench_scheduler", bench_scheduler),
-    ("bench_fl_collectives", bench_fl_collectives),
-    ("bench_kernels", bench_kernels),
-    ("bench_dryrun_roofline", bench_dryrun_roofline),
     ("fig1_controlled", fig1_controlled),
     ("fig2_dirichlet", fig2_dirichlet),
     ("scheme_race", scheme_race),

@@ -18,9 +18,9 @@ Phases, each printing its wall and compile seconds on its own line:
   (c) sketched  the same spec with the SRP-sketched, k-means, async planner
   (d) reference the fused distances on (b)'s store against the float64 numpy
                 reference, the SRP sketch against its host reference, and one
-                batched round against the compat engine from the same state
-  (e) engine    every server ran the batched engine (not the compat fallback)
-                and the kernels lowered as compiled Mosaic calls
+                batched round against the reference per-client loop
+                (``repro.fl.client.ReferenceRoundEngine``) from the same state
+  (e) kernels   the kernels lowered as compiled Mosaic calls
 
 ``--four-chips`` runs only the sharded path and what it is compared with:
 the spec of (b) with m = 8 on a ``4x1`` client mesh, against the same spec on
@@ -107,7 +107,7 @@ def run_phase(name: str, fn, *args, **kwargs):
     return out
 
 
-def spec_dict(size: dict, *, planner=None, mesh_spec=None, m=None, engine="batched") -> dict:
+def spec_dict(size: dict, *, planner=None, mesh_spec=None, m=None) -> dict:
     """The ExperimentSpec dict of a served run at ``size`` (e.g. :data:`PAPER`)."""
     spec = {
         "data": {
@@ -119,7 +119,7 @@ def spec_dict(size: dict, *, planner=None, mesh_spec=None, m=None, engine="batch
             },
         },
         "sampler": {"name": "algorithm2", "m": m or size["m"], "seed": 3},
-        "engine": {"name": engine, "mesh_spec": mesh_spec},
+        "engine": {"name": "batched", "mesh_spec": mesh_spec},
         "train": {
             "n_rounds": ROUNDS,
             "n_local_steps": size["n_local_steps"],
@@ -170,7 +170,6 @@ class ServedRun:
     spec: object
     history: object
     store: object
-    engine: object
     checkpoint: str
 
 
@@ -225,9 +224,7 @@ def phase_served(size: dict, out_dir: Path, ds) -> ServedRun:
     losses = [r.train_loss for r in full.records]
     print(f"  {len(full.records)} rounds, losses {losses}; resume after {KILL_AT} "
           "rounds is byte-identical", flush=True)
-    return ServedRun(
-        spec, full, srv.sampler.gradient_store.snapshot(), srv._engine, full_ck
-    )
+    return ServedRun(spec, full, srv.sampler.gradient_store.snapshot(), full_ck)
 
 
 def phase_sketched(size: dict, out_dir: Path, dataset) -> ServedRun:
@@ -249,7 +246,7 @@ def phase_sketched(size: dict, out_dir: Path, dataset) -> ServedRun:
         raise AssertionError(f"non-finite losses {losses}")
     print(f"  {len(hist.records)} rounds, losses {losses}, store {store.nbytes} bytes",
           flush=True)
-    return ServedRun(spec, hist, store.snapshot(), srv._engine, ck)
+    return ServedRun(spec, hist, store.snapshot(), ck)
 
 
 def phase_reference(served: ServedRun, dataset) -> dict:
@@ -259,6 +256,8 @@ def phase_reference(served: ServedRun, dataset) -> dict:
 
     from repro.core.clustering import pairwise_distances
     from repro.fl.aggregation import flatten_params
+    from repro.fl.client import reference_engine
+    from repro.fl.engine import ENGINES, register_engine
     from repro.fl.experiment import build_experiment
     from repro.kernels.similarity.ops import resolve_distance_backend
     from repro.kernels.sketch import SKETCHERS
@@ -284,48 +283,52 @@ def phase_reference(served: ServedRun, dataset) -> dict:
         sketcher.reference(X),
     )
 
-    # one more round from (b)'s final checkpoint on each engine
+    # one more round from (b)'s final checkpoint on each engine; the reference
+    # loop is no built-in engine, so it is registered for this phase only
+    added = "reference" not in ENGINES
+    if added:
+        register_engine("reference", reference_engine)
     rounds = {}
-    for engine in ("batched", "compat"):
-        spec = dataclasses.replace(
-            served.spec, engine=dataclasses.replace(served.spec.engine, name=engine)
-        )
-        with build_experiment(spec, dataset=dataset, checkpoint_path=served.checkpoint) as srv:
-            t = srv.resume()
-            rec = srv.run_round(t)
-        rounds[engine] = (rec, np.asarray(flatten_params(srv.params)))
-    (rb, pb), (rc, pc) = rounds["batched"], rounds["compat"]
-    if not np.array_equal(rb.agg_weights, rc.agg_weights):
+    try:
+        for engine in ("batched", "reference"):
+            spec = dataclasses.replace(
+                served.spec, engine=dataclasses.replace(served.spec.engine, name=engine)
+            )
+            with build_experiment(
+                spec, dataset=dataset, checkpoint_path=served.checkpoint
+            ) as srv:
+                t = srv.resume()
+                rec = srv.run_round(t)
+            rounds[engine] = (rec, np.asarray(flatten_params(srv.params)))
+    finally:
+        if added:
+            ENGINES.unregister("reference")
+    (rb, pb), (rr, pr) = rounds["batched"], rounds["reference"]
+    if not np.array_equal(rb.agg_weights, rr.agg_weights):
         raise AssertionError("the two engines drew different clients from one state")
-    errs["round_params"] = check_close("batched vs compat round, params", pb, pc)
+    errs["round_params"] = check_close("batched vs reference round, params", pb, pr)
     errs["round_loss"] = check_close(
-        "batched vs compat round, train loss", rb.train_loss, rc.train_loss
+        "batched vs reference round, train loss", rb.train_loss, rr.train_loss
     )
     return errs
 
 
-def phase_engine(runs) -> None:
-    """(e) The batched engine ran, and the kernels are compiled, not interpreted."""
+def phase_kernels(served: ServedRun) -> None:
+    """(e) The kernels are compiled on the TPU and interpreted elsewhere."""
     import jax
     import jax.numpy as jnp
 
-    from repro.fl.engine import BatchedRoundEngine
     from repro.kernels import interpret_mode
     from repro.kernels.similarity.ops import resolve_distance_backend
     from repro.kernels.sketch import SKETCHERS
 
-    for run in runs:
-        if not isinstance(run.engine, BatchedRoundEngine):
-            raise AssertionError(
-                f"server ran engine {type(run.engine).__name__}, not the batched engine"
-            )
     interpret = interpret_mode()
-    n, d = runs[0].store.shape
+    n, d = served.store.shape
     dist = resolve_distance_backend("auto", as_numpy=False)
     sketch = SKETCHERS.get("srp")(d, SKETCH_DIM, seed=0)
     for name, fn, shape in (
         ("similarity", lambda g: dist(g, "arccos"), (n, d)),
-        ("srp sketch", lambda x: sketch(x), (runs[0].spec.sampler.m, d)),
+        ("srp sketch", lambda x: sketch(x), (served.spec.sampler.m, d)),
     ):
         arg = jax.ShapeDtypeStruct(shape, jnp.float32)
         compiled_kernel = "tpu_custom_call" in jax.jit(fn).lower(arg).as_text()
@@ -334,8 +337,8 @@ def phase_engine(runs) -> None:
                 f"{name} kernel: interpret mode {interpret} on "
                 f"{jax.default_backend()!r}, but tpu_custom_call present: {compiled_kernel}"
             )
-    print(f"  {len(runs)} servers on the batched engine; kernels "
-          f"{'interpreted' if interpret else 'compiled (tpu_custom_call)'}", flush=True)
+    print(f"  kernels {'interpreted' if interpret else 'compiled (tpu_custom_call)'}",
+          flush=True)
 
 
 def phase_sharded(size: dict, out_dir: Path, dataset, *, m: int, chips: int = 4) -> dict:
@@ -397,9 +400,9 @@ def main(argv=None) -> int:
         run_phase("four-chip sharded", phase_sharded, PAPER, out, ds, m=8, chips=chips)
     else:
         served = run_phase("b served", phase_served, PAPER, out, ds)
-        sketched = run_phase("c sketched", phase_sketched, PAPER, out, ds)
+        run_phase("c sketched", phase_sketched, PAPER, out, ds)
         run_phase("d reference", phase_reference, served, ds)
-        run_phase("e engine", phase_engine, [served, sketched])
+        run_phase("e kernels", phase_kernels, served)
     device = {"platform": info["platform"], "kind": info["kind"], "count": chips}
     print(json.dumps({"ok": True, "device": device}), flush=True)
     return 0

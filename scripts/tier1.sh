@@ -1,5 +1,6 @@
 #!/usr/bin/env bash
-# Tier-1 verification: the full test suite + benchmark smoke runs.
+# Tier-1 verification: the full test suite + the spec, sweep, scheme-race and
+# service smoke runs.
 #
 # Collection errors (missing optional deps, jax API drift) take down whole
 # test modules silently under plain `pytest path` invocations — this script
@@ -11,16 +12,6 @@ export PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}"
 
 echo "== tier-1: pytest =="
 python -m pytest -q
-
-echo "== tier-1: benchmark smoke (import + run sanity) =="
-python -m benchmarks.bench_sampler_cost --smoke
-python -m benchmarks.bench_round_engine --smoke
-python -m benchmarks.bench_engine_sharded --smoke
-python -m benchmarks.bench_async_planner --smoke --drift
-python -m benchmarks.bench_service_churn --smoke
-python -m benchmarks.bench_store_scale --smoke
-# asserts sync ≡ scheduler-free bit-parity + deadline harvest internally
-python -m benchmarks.bench_scheduler --smoke
 
 echo "== tier-1: fused streamed kernel parity vs numpy (ragged-chunk shape) =="
 # 13x101 is ragged against both the 8-row and 16-column tiles AND the

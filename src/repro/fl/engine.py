@@ -1,21 +1,25 @@
-"""Batched on-device FL round engine.
+"""Batched on-device FL round engine: the server's one round path.
 
-The paper's protocol runs ``m`` sampled clients per round. The seed server
-trained them one-by-one in a Python loop — m jitted dispatches plus m
-host-side parameter copies per round, so wall-clock grows linearly in m.
-This engine runs the *whole round* as ONE jitted step:
+The paper's protocol runs ``m`` sampled clients per round. A per-client loop
+(:class:`repro.fl.client.ReferenceRoundEngine`, kept as the tests'
+reference) costs m jitted dispatches plus m host-side copies of client data
+per round, so wall-clock grows linearly in m. This engine runs the *whole
+round* as ONE jitted step:
 
   1. every client's train set is padded to a common length and stacked once
      into a device-resident (n, n_pad, …) block at construction time;
   2. per round, the distinct sampled clients are gathered *on device* by
      slot index, and all local updates run as ``vmap(local_steps)`` — the
-     same ``lax.scan`` body as the ``compat`` path, so the two paths agree
-     to fp32 tolerance (FedProx proximal term included);
+     same ``lax.scan`` body as the reference loop, so the two agree to fp32
+     tolerance (FedProx proximal term included);
   3. the weighted aggregation (eq. 3/4 incl. ``stale_weight``) and the
      flattened representative gradients ``θ_i^{t+1} − θ^t`` (Algorithm 2
      line 1's input, fed to ``sampler.observe_updates``) are computed in the
      same jitted step — nothing round-trips through the host except the
      (m, N, B) batch-index block and the scalar losses.
+
+The whole fleet is staged: a fleet the device cannot hold fails with the
+device's own allocation error at construction.
 
 Shapes are static across the run: the client axis is always padded to
 ``m_slots`` (zero weight ⇒ zero contribution for unused slots), so the
@@ -24,7 +28,7 @@ clients each round realizes. Per-round padding waste is ``m_slots −
 n_distinct`` client-updates — small, because clustered sampling exists
 precisely to keep the draws distinct.
 
-RNG discipline matches the compat loop exactly: batch indices are drawn
+RNG discipline matches the reference loop exactly: batch indices are drawn
 from the server's host rng per distinct client, in distinct order, and
 padded slots consume no randomness — so the same seed yields the same
 realized batches on both paths.
@@ -77,31 +81,6 @@ def _staged_dtypes(dataset) -> tuple[np.dtype, np.dtype]:
     feat = xd if (xd.kind == "f" and xd.itemsize <= 4) else np.dtype(np.float32)
     lab = yd if (yd.kind in "iu" and yd.itemsize <= 4) else np.dtype(np.int32)
     return feat, lab
-
-
-def staged_bytes(
-    dataset, m_slots: int = 0, n_steps: int = 0, batch_size: int = 0, mesh=None
-) -> int:
-    """*Per-device* bytes the engine pins for ``dataset``: every client
-    padded to the largest client, in the dtypes the engine actually stages
-    (see :func:`_staged_dtypes`), plus the per-round ``(m_slots, n_steps,
-    batch_size)`` i32 batch-index block the server ships each round.
-
-    With ``mesh``, each term shrinks by the data-parallel degree when its
-    leading axis divides it — mirroring how the engine actually shards (it
-    stages replicated on uneven client counts)."""
-    n_pad = max(c.n_train for c in dataset.clients)
-    feat = int(np.prod(dataset.clients[0].x_train.shape[1:]))
-    feat_dt, label_dt = _staged_dtypes(dataset)
-    data = dataset.n_clients * n_pad * (feat * feat_dt.itemsize + label_dt.itemsize)
-    idx = m_slots * n_steps * batch_size * np.dtype(np.int32).itemsize
-    if mesh is not None:
-        n_dp = data_parallel_degree(mesh)
-        if dataset.n_clients % n_dp == 0:
-            data //= n_dp
-        if m_slots % n_dp == 0:
-            idx //= n_dp
-    return data + idx
 
 
 def _client_spec(mesh, ndim: int) -> NamedSharding:
@@ -202,11 +181,8 @@ class BatchedRoundEngine:
             self._y_all = jax.device_put(y_all, y_sh)
 
     def per_device_staged_bytes(self) -> int:
-        """Measured bytes the busiest device pins for the staged dataset.
-
-        The per-round batch-index block is a transient, not counted here —
-        :func:`staged_bytes` is the planning-time estimate that includes it.
-        """
+        """Measured bytes the busiest device pins for the staged dataset
+        (the per-round batch-index block is a transient, not counted)."""
         per_device: dict = {}
         for arr in (self._x_all, self._y_all):
             for shard in arr.addressable_shards:
@@ -234,7 +210,7 @@ class BatchedRoundEngine:
             slot_ids[:c] = distinct
             idx = np.zeros((self.m_slots, self.n_steps, self.batch_size), dtype=np.int32)
             for i, cid in enumerate(distinct):
-                # same rng stream as the compat loop's draw_batch_indices, drawn
+                # same rng stream as the reference loop's draw_batch_indices, drawn
                 # host-side (one device transfer for the whole block below)
                 idx[i] = rng.integers(
                     0, int(self._n_train[int(cid)]), size=(self.n_steps, self.batch_size)
@@ -276,15 +252,9 @@ def _batched_engine(dataset, m: int, config, mesh):
     )
 
 
-def _compat_engine(dataset, m: int, config, mesh):
-    """The per-client reference loop lives in the server; no engine object."""
-    del dataset, m, config, mesh
-    return None
-
-
-#: name -> factory(dataset, m, config, mesh) returning an object with
+#: name -> factory(dataset, m, config, mesh) returning an engine object with
 #: ``run_round(params, distinct, weights, stale_weight, rng, loss_fn, opt,
-#: fedprox_mu)`` — or None to select the server's compat per-client loop.
-ENGINES = Registry("engine", {"batched": _batched_engine, "compat": _compat_engine})
+#: fedprox_mu)`` -> (new_params, (c, d) flat updates, (c,) losses).
+ENGINES = Registry("engine", {"batched": _batched_engine})
 
 register_engine = ENGINES.register

@@ -226,7 +226,6 @@ class EngineSpec:
     name: str = "batched"
     # None | "auto" | "DxM" | (D, M) — see repro.launch.mesh.resolve_fl_mesh
     mesh_spec: Union[str, tuple, None] = None
-    max_staged_bytes: int = 2 << 30
 
     def __post_init__(self):
         if isinstance(self.mesh_spec, list):
@@ -246,7 +245,6 @@ class EngineSpec:
         return {
             "name": self.name,
             "mesh_spec": list(mesh) if isinstance(mesh, tuple) else mesh,
-            "max_staged_bytes": self.max_staged_bytes,
         }
 
 
@@ -592,7 +590,6 @@ def build_experiment(
         eval_every=tr.eval_every,
         seed=tr.seed,
         engine=spec.engine.name,
-        max_staged_bytes=spec.engine.max_staged_bytes,
         mesh_spec=spec.engine.mesh_spec,
         checkpoint_every=tr.checkpoint_every,
         checkpoint_path=checkpoint_path,

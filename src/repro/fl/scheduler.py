@@ -10,8 +10,8 @@ two. This module makes the round-closing rule a pluggable policy, a
 
 * ``"sync"`` — today's behaviour, the exact legacy path. Every hook is a
   no-op; a server with a :class:`SyncScheduler` attached trains
-  bit-identically to one with no scheduler at all (tier-1 parity gate in
-  ``benchmarks/bench_scheduler.py``).
+  bit-identically to one with no scheduler at all (parity gate in
+  ``tests/test_scheduler.py``).
 * ``"deadline"`` — rounds close after a fixed deadline against a simulated
   per-client :class:`LatencyModel`, drawn pure in ``(seed, t)`` exactly
   like :mod:`repro.fl.population`'s masks (same ``SeedSequence`` keying,

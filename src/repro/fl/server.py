@@ -13,13 +13,14 @@ Faithful to the paper's protocol:
     the plan rebuild they trigger overlaps the next round's local work, and
     each ``RoundRecord`` carries ``plan_version`` / ``plan_lag_rounds``.
 
-Two execution engines (``FLConfig.engine``):
-  * ``"batched"`` (default) — the whole round is one jitted
-    vmap-over-clients step (:mod:`repro.fl.engine`); client data lives on
-    device for the entire run.
-  * ``"compat"`` — the original per-client Python loop, kept as the
-    numerics reference; ``tests/test_round_engine.py`` pins the two paths
-    together to fp32 tolerance.
+One round path: the engine named by ``FLConfig.engine`` (an ``ENGINES``
+name; ``"batched"``, the only one built in) runs each round's local work and
+aggregation. The batched engine runs the whole round as one jitted
+vmap-over-clients step (:mod:`repro.fl.engine`), with client data on device
+for the entire run. The per-client loop
+(:class:`repro.fl.client.ReferenceRoundEngine`) is the tests' numerics
+reference, registered by the tests, not a choice a config can make;
+``tests/test_round_engine.py`` pins the two together to fp32 tolerance.
 
 Continuous service (the churn-tolerant path): a
 :class:`~repro.fl.population.PopulationProcess` turns the fixed-n batch
@@ -63,7 +64,6 @@ from __future__ import annotations
 
 import dataclasses
 import json
-import warnings
 from typing import Callable, Optional
 
 import jax
@@ -74,9 +74,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.samplers.base import ClientSampler
 from repro.data.federated import FederatedDataset
-from repro.fl.aggregation import aggregate_round, flatten_params
-from repro.fl.client import draw_batch_indices, local_update
-from repro.fl.engine import ENGINES, staged_bytes
+from repro.fl.engine import ENGINES
 from repro.fl.history import History, RoundRecord
 from repro.fl.population import PopulationProcess
 from repro.launch.mesh import resolve_fl_mesh
@@ -93,14 +91,10 @@ class FLConfig:
     eval_every: int = 1
     seed: int = 0
     engine: str = "batched"  # any repro.fl.engine.ENGINES name
-    # The batched engine pins every client's (padded) data on device. If that
-    # exceeds this budget the server falls back to the memory-lean compat
-    # loop with a warning — both paths are numerically equivalent.
-    max_staged_bytes: int = 2 << 30
     # Mesh for the batched engine's client axis: None (single-device,
     # default), "auto" (all local devices on "data"), "DxM" / (D, M) host
     # mesh shapes, or a jax.sharding.Mesh. See repro.launch.mesh.
-    # resolve_fl_mesh and the engine module docstring. Ignored by "compat".
+    # resolve_fl_mesh and the engine module docstring.
     mesh_spec: "str | tuple[int, int] | None" = None
     # Crash tolerance: every `checkpoint_every` completed rounds (and on a
     # service stop request) the full ServerState bundle is written to
@@ -156,33 +150,13 @@ class FederatedServer:
         # classes each client can contribute — O(total samples) once, so the
         # per-round distinct-class count is a union of tiny class sets
         self._client_classes = [np.unique(c.y_train) for c in dataset.clients]
-        mesh = (
-            resolve_fl_mesh(config.mesh_spec) if config.engine != "compat" else None
-        )
+        mesh = resolve_fl_mesh(config.mesh_spec)
         # the scheduler owns the engine's padded slot count (all built-ins
         # keep it at m — overselection thins at draw time — but the contract
         # lets a custom scheduler stage wider rounds)
         slots = (
             sampler.m if scheduler is None else int(scheduler.required_slots(sampler.m))
         )
-        if config.engine == "batched":
-            # budget check against the *per-device* footprint: a mesh that
-            # shards the client axis is exactly how huge datasets stay stageable
-            need = staged_bytes(
-                dataset, slots, config.n_local_steps, config.batch_size, mesh=mesh
-            )
-            if need > config.max_staged_bytes:
-                fmt = lambda b: f"{b / 2**30:.2f} GiB" if b >= 2**30 else f"{b / 2**20:.2f} MiB"
-                warnings.warn(
-                    f"batched engine would stage {fmt(need)} of padded "
-                    f"client data per device (budget {fmt(config.max_staged_bytes)}); "
-                    "falling back to the compat loop — raise FLConfig.max_staged_bytes "
-                    "or shard further via FLConfig.mesh_spec to override",
-                    stacklevel=2,
-                )
-                engine_factory = ENGINES.get("compat")
-                mesh = None  # the compat loop never shards; a stale mesh here
-                # would be handed to the factory and pin devices for nothing
         self._engine = engine_factory(dataset, slots, config, mesh)
         self._stage_test_set(mesh)
         # service cursor: the next round to run. run()/resume() maintain it so
@@ -197,7 +171,7 @@ class FederatedServer:
         With no mesh it lands on the default device; on a mesh it is
         replicated, as the round step's global params are, so eval moves no
         data in any round. It stays resident beside the engine's staged
-        client data but is not counted against ``max_staged_bytes``.
+        client data.
         """
         x, y = self.dataset.global_test()
         if mesh is None:
@@ -210,33 +184,6 @@ class FederatedServer:
         )
         with TraceAnnotation("fl.eval.stage", bytes=self._test_bytes):
             self._x_test, self._y_test = place(x), place(y)
-
-    # ------------------------------------------------------------------
-    def _round_compat(self, distinct: np.ndarray, weights: np.ndarray, stale_weight: float):
-        """Reference path: one jitted dispatch per distinct client."""
-        cfg = self.cfg
-        client_models, losses, updates_flat = [], [], []
-        for cid in distinct:
-            data = self.dataset.clients[int(cid)]
-            idx = draw_batch_indices(
-                self._rng, data.n_train, cfg.n_local_steps, cfg.batch_size
-            )
-            new_p, loss = local_update(
-                self.params,
-                jnp.asarray(data.x_train),
-                jnp.asarray(data.y_train),
-                idx,
-                self.loss_fn,
-                self.opt,
-                cfg.fedprox_mu,
-            )
-            client_models.append(new_p)
-            losses.append(float(loss))
-            updates_flat.append(
-                np.asarray(flatten_params(new_p) - flatten_params(self.params))
-            )
-        new_params = aggregate_round(self.params, client_models, weights, stale_weight)
-        return new_params, np.stack(updates_flat), np.asarray(losses)
 
     # -- round phases --------------------------------------------------------
     # run_round = availability → draw → drop resolution → local work +
@@ -335,18 +282,16 @@ class FederatedServer:
 
     def _phase_local_work(self, distinct, weights, stale_weight):
         """Local training + aggregation — one fused engine dispatch."""
-        if self._engine is not None:
-            return self._engine.run_round(
-                self.params,
-                distinct,
-                weights,
-                stale_weight,
-                self._rng,
-                self.loss_fn,
-                self.opt,
-                self.cfg.fedprox_mu,
-            )
-        return self._round_compat(distinct, weights, stale_weight)
+        return self._engine.run_round(
+            self.params,
+            distinct,
+            weights,
+            stale_weight,
+            self._rng,
+            self.loss_fn,
+            self.opt,
+            self.cfg.fedprox_mu,
+        )
 
     def _phase_eval(self, t: int) -> float:
         """Test accuracy of the current model; NaN off the ``eval_every`` cadence."""

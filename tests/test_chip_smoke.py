@@ -2,7 +2,7 @@
 
 The script's ``main`` refuses to run off the TPU; its phase functions do
 not, so the served path, the in-process kill/resume byte equality, the
-reference comparisons and the engine check stay covered here.
+reference comparisons and the kernel check stay covered here.
 """
 import importlib.util
 import json
@@ -73,13 +73,16 @@ def test_reference_phase_within_tolerance(smoke, runs):
     assert all(0.0 <= e <= smoke.TOL for e in errs.values())
 
 
-def test_engine_phase_accepts_batched_and_rejects_compat(smoke, runs):
-    import dataclasses
+def test_kernel_phase_rejects_kernels_lowered_against_the_platform(smoke, runs, monkeypatch):
+    """Interpreted kernels pass on the CPU; a platform that claims compiled
+    kernels while the similarity kernel lowers interpreted fails the phase."""
+    import repro.kernels
 
-    _, served, sketched, _ = runs
-    smoke.phase_engine([served, sketched])
-    with pytest.raises(AssertionError, match="not the batched engine"):
-        smoke.phase_engine([dataclasses.replace(served, engine=None)])
+    _, served, _, _ = runs
+    smoke.phase_kernels(served)
+    monkeypatch.setattr(repro.kernels, "interpret_mode", lambda: False)
+    with pytest.raises(AssertionError, match="similarity kernel: interpret mode False"):
+        smoke.phase_kernels(served)
 
 
 def test_device_phase_refuses_the_cpu(smoke):

@@ -18,6 +18,7 @@ from repro.core.samplers.algorithm1 import Algorithm1Sampler
 from repro.core.types import SamplingPlan
 from repro.fl import ENGINES, FederatedServer, FLConfig, by_class_shards, register_engine
 from repro.fl.aggregation import flatten_params
+from repro.fl.client import ReferenceRoundEngine, reference_engine
 from repro.fl.experiment import (
     DATASETS,
     DataSpec,
@@ -76,7 +77,7 @@ def test_sub_specs_round_trip():
         (DataSpec, DATA),
         (SamplerSpec, {"name": "md", "m": 7, "seed": 3}),
         (PlannerSpec, {"mode": "async", "rebuild_every": 5}),
-        (EngineSpec, {"name": "compat", "max_staged_bytes": 123}),
+        (EngineSpec, {"name": "batched", "mesh_spec": "2x2"}),
         (TrainSpec, {"n_rounds": 2, "hidden": [8, 8], "n_classes": 4}),
     ):
         spec = cls.from_dict(d)
@@ -97,6 +98,7 @@ def test_engine_spec_mesh_tuple_round_trip():
         (SamplerSpec, {"name": "md", "m": 4, "planner": "sync"}),
         (PlannerSpec, {"mode": "sync", "every": 2}),
         (EngineSpec, {"engine": "batched"}),
+        (EngineSpec, {"max_staged_bytes": 123}),
         (TrainSpec, {"rounds": 5}),
         (ExperimentSpec, {"data": DATA, "sampler": {"name": "md", "m": 4}, "sweep": []}),
     ],
@@ -249,7 +251,7 @@ def test_register_engine_reaches_server(dataset):
 
     def probe_engine(ds, m, config, mesh):
         calls.append((m, config.engine))
-        return None  # fall through to the compat loop
+        return reference_engine(ds, m, config, mesh)
 
     register_engine("probe", probe_engine)
     try:
@@ -258,6 +260,7 @@ def test_register_engine_reaches_server(dataset):
         with build_experiment(spec, dataset=dataset) as srv:
             hist = srv.run()
         assert calls == [(4, "probe")]
+        assert isinstance(srv._engine, ReferenceRoundEngine)
         assert np.isfinite(hist.series("train_loss")).all()
     finally:
         ENGINES.unregister("probe")

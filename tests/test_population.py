@@ -149,7 +149,7 @@ def _server(dataset, *, population, engine="batched", rounds=2, seed=0, m=10):
     )
 
 
-@pytest.mark.parametrize("engine", ["batched", "compat"])
+@pytest.mark.parametrize("engine", ["batched", "reference"])
 def test_degraded_round_aggregates_survivors_only(dataset, engine):
     """Mid-round dropout with >= 1 live client: the dropped participants'
     weight is zeroed (their mass goes stale — the global model keeps it),
@@ -192,7 +192,7 @@ def test_degraded_round_aggregates_survivors_only(dataset, engine):
     assert rec.train_loss == pytest.approx(rec_b.train_loss, rel=1e-5)
 
 
-@pytest.mark.parametrize("engine", ["batched", "compat"])
+@pytest.mark.parametrize("engine", ["batched", "reference"])
 def test_all_dropped_raises_empty_round_with_index(dataset, engine):
     """Every realized participant dropping is an EmptyRoundError naming the
     round — all realized aggregation mass is gone."""

@@ -1,4 +1,4 @@
-"""Batched round engine vs the compat looped path, plus its failure modes."""
+"""Batched round engine vs the reference per-client loop, plus its failure modes."""
 import numpy as np
 import pytest
 
@@ -34,20 +34,20 @@ def _server(dataset, sampler, engine, *, rounds=4, mu=0.0, seed=0):
 
 @pytest.mark.parametrize("mu", [0.0, 0.1], ids=["plain", "fedprox"])
 @pytest.mark.parametrize("cls", [MDSampler, Algorithm1Sampler])
-def test_batched_matches_compat(dataset, cls, mu):
+def test_batched_matches_reference(dataset, cls, mu):
     """Same sampler + server seed ⇒ identical realized rounds on both
     engines; final params must agree within fp32 tolerance."""
     pop = dataset.population
     runs = {}
-    for engine in ("batched", "compat"):
+    for engine in ("batched", "reference"):
         srv = _server(dataset, cls(pop, 10, seed=7), engine, mu=mu)
         srv.run()
         runs[engine] = srv
     fa = np.asarray(flatten_params(runs["batched"].params))
-    fb = np.asarray(flatten_params(runs["compat"].params))
+    fb = np.asarray(flatten_params(runs["reference"].params))
     np.testing.assert_allclose(fa, fb, rtol=1e-4, atol=1e-5)
     la = np.array(runs["batched"].history.series("train_loss"))
-    lb = np.array(runs["compat"].history.series("train_loss"))
+    lb = np.array(runs["reference"].history.series("train_loss"))
     np.testing.assert_allclose(la, lb, rtol=1e-4, atol=1e-6)
 
 
@@ -58,7 +58,7 @@ def test_batched_handles_stale_mass(dataset):
 
     pop = dataset.population
     a = _server(dataset, SAMPLERS["uniform"](pop, 10, seed=3), "batched", rounds=3)
-    b = _server(dataset, SAMPLERS["uniform"](pop, 10, seed=3), "compat", rounds=3)
+    b = _server(dataset, SAMPLERS["uniform"](pop, 10, seed=3), "reference", rounds=3)
     a.run(), b.run()
     np.testing.assert_allclose(
         np.asarray(flatten_params(a.params)),
@@ -78,7 +78,7 @@ class _EmptySampler(ClientSampler):
         )
 
 
-@pytest.mark.parametrize("engine", ["batched", "compat"])
+@pytest.mark.parametrize("engine", ["batched", "reference"])
 def test_zero_distinct_clients_raises_clearly(dataset, engine):
     srv = _server(dataset, _EmptySampler(dataset.population, 10), engine, rounds=1)
     with pytest.raises(EmptyRoundError, match="zero\\s+distinct clients"):
@@ -160,11 +160,10 @@ def test_fl_engine_step_lowers_image_shaped_clients():
     del new_params
 
 
-def test_staged_bytes_counts_index_block_and_dtypes():
-    """The footprint estimate must match what the engine actually stages:
-    native (narrow) dtypes plus the per-round (m, N, B) i32 index block."""
+def test_engine_stages_native_dtypes():
+    """The engine stages narrow dtypes as they are (an int8 label stays one
+    byte), and ``per_device_staged_bytes`` counts exactly what it staged."""
     from repro.data.federated import ClientData, FederatedDataset
-    from repro.fl.engine import staged_bytes
 
     rng = np.random.default_rng(0)
     clients = [
@@ -177,13 +176,9 @@ def test_staged_bytes_counts_index_block_and_dtypes():
         for _ in range(4)
     ]
     ds = FederatedDataset(clients)
-    # 4 clients x 30 rows x (8 f32 features + 1 int8 label)
-    base = 4 * 30 * (8 * 4 + 1)
-    assert staged_bytes(ds) == base
-    assert staged_bytes(ds, m_slots=3, n_steps=5, batch_size=7) == base + 3 * 5 * 7 * 4
-
     eng = BatchedRoundEngine(ds, m_slots=3, n_steps=5, batch_size=7)
-    assert eng._x_all.nbytes + eng._y_all.nbytes == base
+    # 4 clients x 30 rows x (8 f32 features + 1 int8 label)
+    assert eng.per_device_staged_bytes() == 4 * 30 * (8 * 4 + 1)
     assert eng._y_all.dtype == np.int8
 
 
@@ -198,7 +193,7 @@ class _ZeroWeightSampler(ClientSampler):
         )
 
 
-@pytest.mark.parametrize("engine", ["batched", "compat"])
+@pytest.mark.parametrize("engine", ["batched", "reference"])
 def test_zero_realized_weight_raises_instead_of_nan_loss(dataset, engine):
     """A round whose realized weights sum to 0 must fail loudly, not log a
     silent NaN train_loss (0/0 in the weighted average)."""
@@ -208,44 +203,11 @@ def test_zero_realized_weight_raises_instead_of_nan_loss(dataset, engine):
     assert len(srv.history.records) == 0
 
 
-def test_staging_budget_falls_back_to_compat(dataset):
-    """A dataset too big to pin on device degrades to the compat loop with a
-    warning instead of OOMing at construction."""
+@pytest.mark.parametrize("name", ["turbo", "compat"])
+def test_unknown_engine_rejected(dataset, name):
+    """``compat`` is no engine name: the per-client loop is the tests'
+    reference, registered by the tests alone."""
     params = init_mlp((16, 32, 10), seed=1)
-    cfg = FLConfig(n_rounds=1, n_local_steps=2, batch_size=8, max_staged_bytes=1)
-    with pytest.warns(UserWarning, match="falling back to the compat loop"):
-        srv = FederatedServer(dataset, MDSampler(dataset.population, 10), params, sgd(0.1), cfg)
-    assert srv._engine is None
-    rec = srv.run_round(0)
-    assert np.isfinite(rec.train_loss)
-
-
-def test_staging_budget_fallback_drops_stale_mesh(dataset, monkeypatch):
-    """The over-budget fallback must hand the compat factory mesh=None: the
-    mesh resolved for the batched engine is dead weight once the fallback
-    triggers (it would pin devices for an engine that never shards)."""
-    from repro.fl.engine import ENGINES
-
-    seen = {}
-
-    def spy_compat(ds, m, config, mesh):
-        seen["mesh"] = mesh
-        return None
-
-    monkeypatch.setitem(ENGINES._entries, "compat", spy_compat)
-    params = init_mlp((16, 32, 10), seed=1)
-    cfg = FLConfig(
-        n_rounds=1, n_local_steps=2, batch_size=8,
-        max_staged_bytes=1, mesh_spec="auto",
-    )
-    with pytest.warns(UserWarning, match="falling back to the compat loop"):
-        srv = FederatedServer(dataset, MDSampler(dataset.population, 10), params, sgd(0.1), cfg)
-    assert seen["mesh"] is None
-    assert srv._engine is None
-
-
-def test_unknown_engine_rejected(dataset):
-    params = init_mlp((16, 32, 10), seed=1)
-    cfg = FLConfig(n_rounds=1, engine="turbo")
+    cfg = FLConfig(n_rounds=1, engine=name)
     with pytest.raises(ValueError, match="unknown engine"):
         FederatedServer(dataset, MDSampler(dataset.population, 10), params, sgd(0.1), cfg)

@@ -37,7 +37,7 @@ PARENT = {
 }
 #: Spans that open only in a round where a participant dropped or was late.
 DEGRADED_ONLY = {"fl.observe.gather"}
-ENGINES = ["batched", "compat"]
+ENGINES = ["batched", "reference"]
 #: The client that :class:`_DropOne` drops whenever it is drawn.
 DROPPED = 2
 
@@ -211,8 +211,8 @@ def test_a_traced_run_computes_what_an_untraced_run_computes(dataset, traced):
         np.testing.assert_array_equal(np.asarray(v), np.asarray(traced["srv"].params[k]))
 
 
-def test_the_compat_loop_has_the_server_spans_only(dataset, tmp_path):
-    _, events = _traced_run(_server(dataset, engine="compat"), tmp_path)
+def test_the_reference_loop_has_the_server_spans_only(dataset, tmp_path):
+    _, events = _traced_run(_server(dataset, engine="reference"), tmp_path)
     names = {e[0] for e in events}
     assert names == {"fl.round"} | set(PARENT) - DEGRADED_ONLY - {
         "fl.local_work.prep", "fl.local_work.dispatch", "fl.local_work.wait"}
@@ -312,7 +312,7 @@ def test_the_test_set_is_staged_once_at_construction(dataset, tmp_path):
     assert stage[3]["bytes"] == srv._x_test.nbytes + srv._y_test.nbytes == srv._test_bytes
 
 
-@pytest.mark.parametrize("engine", ["batched", "compat"])
+@pytest.mark.parametrize("engine", ENGINES)
 def test_every_round_evaluates_on_the_same_device_arrays(dataset, tmp_path, engine):
     srv = _server(dataset, engine=engine)
     seen, acc_fn = [], srv.acc_fn
