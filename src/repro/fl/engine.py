@@ -36,8 +36,9 @@ realized batches on both paths.
 Tracing: a round's engine work shows in a profiler trace as three spans
 inside the server's ``fl.local_work`` — ``fl.local_work.prep`` (slot ids,
 batch indices, weights; counters ``distinct`` and ``slots``),
-``fl.local_work.dispatch`` (the step's call and its ``updates[:c]`` slice;
-counter ``bytes``, what the call moves to the device) and
+``fl.local_work.dispatch`` (the compiled step's call on the host numpy
+inputs and the compiled ``(c, d)`` row slice; no eager ``jax.numpy``
+operation; counter ``bytes``, what the call moves to the device) and
 ``fl.local_work.wait`` (the host blocking on the losses).
 
 Mesh sharding (``mesh=`` on the engine / ``batched_round_step``): the round
@@ -139,6 +140,12 @@ def batched_round_step(
     return new_params, updates, losses
 
 
+@functools.partial(jax.jit, static_argnums=1)
+def _first_rows(updates, c: int):
+    """The ``(c, d)`` rows of the realized clients, one compile per ``c``."""
+    return updates[:c]
+
+
 class BatchedRoundEngine:
     """Stages a :class:`~repro.data.federated.FederatedDataset` once and runs
     rounds through :func:`batched_round_step`.
@@ -220,23 +227,26 @@ class BatchedRoundEngine:
             # the four 32-bit inputs the dispatch moves to the device
             h2d = slot_ids.nbytes + idx.nbytes + w.nbytes + np.dtype(np.float32).itemsize
         with TraceAnnotation("fl.local_work.dispatch", bytes=h2d):
+            # host numpy inputs go straight to the compiled call, which moves
+            # them to the device itself; wrapping them in jnp.asarray first
+            # would add an eager dispatch each
             new_params, updates, losses = batched_round_step(
                 params,
                 self._x_all,
                 self._y_all,
-                jnp.asarray(slot_ids),
-                jnp.asarray(idx),
-                jnp.asarray(w),
-                jnp.asarray(stale_weight, jnp.float32),
+                slot_ids,
+                idx,
+                w,
+                np.float32(stale_weight),
                 loss_fn=loss_fn,
                 opt=opt,
                 fedprox_mu=fedprox_mu,
                 mesh=self.mesh,
             )
             # updates stay a device array: the gradient store scatters them
-            # back into G without a host round-trip (the (m_slots, d) -> (c, d)
-            # slice compiles one tiny gather per distinct-count, c <= m_slots)
-            updates = updates[:c]
+            # back into G without a host round-trip. The (m_slots, d) -> (c, d)
+            # slice is compiled, one variant per distinct-count c <= m_slots
+            updates = _first_rows(updates, c)
         with TraceAnnotation("fl.local_work.wait"):
             losses = np.asarray(losses)[:c]
         return new_params, updates, losses

@@ -97,6 +97,110 @@ def test_engine_rejects_overfull_round(dataset):
         )
 
 
+M_SLOTS = 5
+
+
+def _engine_round_inputs(dataset, c):
+    """An engine of ``M_SLOTS`` slots and one round's inputs for ``c`` of them."""
+    eng = BatchedRoundEngine(dataset, m_slots=M_SLOTS, n_steps=3, batch_size=8)
+    params = init_mlp((16, 32, 10), seed=1)
+    distinct = np.array([7, 42, 3, 91, 18][:c])
+    weights = np.linspace(0.1, 0.3, c)
+    return eng, params, distinct, weights
+
+
+def _eager_dispatch(eng, params, distinct, weights, stale_weight, rng, loss_fn, opt):
+    """The round as the engine dispatched it before: every input wrapped in an
+    eager ``jnp.asarray`` and the rows cut by an eager slice (same prep)."""
+    import jax.numpy as jnp
+
+    from repro.fl.engine import batched_round_step
+
+    c = len(distinct)
+    slot_ids = np.zeros(eng.m_slots, dtype=np.int32)
+    slot_ids[:c] = distinct
+    idx = np.zeros((eng.m_slots, eng.n_steps, eng.batch_size), dtype=np.int32)
+    for i, cid in enumerate(distinct):
+        idx[i] = rng.integers(0, int(eng._n_train[int(cid)]), size=idx.shape[1:])
+    w = np.zeros(eng.m_slots, dtype=np.float32)
+    w[:c] = weights
+    new_params, updates, losses = batched_round_step(
+        params, eng._x_all, eng._y_all,
+        jnp.asarray(slot_ids), jnp.asarray(idx), jnp.asarray(w),
+        jnp.asarray(stale_weight, jnp.float32),
+        loss_fn=loss_fn, opt=opt, fedprox_mu=0.0, mesh=eng.mesh,
+    )
+    return new_params, updates[:c], np.asarray(losses)[:c]
+
+
+@pytest.mark.parametrize("c", [1, M_SLOTS], ids=["1", "m"])
+def test_dispatch_calls_no_eager_jnp(dataset, monkeypatch, c):
+    """Once its shapes are compiled, a round dispatches no eager jax.numpy
+    operation: no ``jnp`` call from the engine's module and no indexing of a
+    device array. It compiles nothing either."""
+    import jax
+
+    from repro.fl import engine as engine_mod
+    from repro.models.simple import classification_loss
+
+    eng, params, distinct, weights = _engine_round_inputs(dataset, c)
+    args = (params, distinct, weights, 0.25)
+    rest = (classification_loss, sgd(0.1))
+    eng.run_round(*args, np.random.default_rng(0), *rest)  # compile this c
+
+    reached = []
+
+    class _SpyJnp:
+        def __init__(self, jnp):
+            self._jnp = jnp
+
+        def __getattr__(self, name):
+            reached.append(f"jnp.{name}")
+            return getattr(self._jnp, name)
+
+    array_cls = type(jax.numpy.zeros(1))
+    getitem = array_cls.__getitem__
+
+    def spy_getitem(self, key):
+        reached.append("jax.Array.__getitem__")
+        return getitem(self, key)
+
+    def compiled():  # cache sizes of the engine module's jitted functions
+        return {k: f._cache_size() for k, f in vars(engine_mod).items() if hasattr(f, "_cache_size")}
+
+    cache = compiled()
+    monkeypatch.setattr(engine_mod, "jnp", _SpyJnp(engine_mod.jnp))
+    monkeypatch.setattr(array_cls, "__getitem__", spy_getitem)
+    new_params, rows, losses = eng.run_round(*args, np.random.default_rng(1), *rest)
+    monkeypatch.undo()
+
+    assert reached == []
+    assert compiled() == cache
+    assert rows.shape[0] == c and losses.shape == (c,)
+    jax.block_until_ready(new_params)
+
+
+@pytest.mark.parametrize("c", [1, 3, M_SLOTS], ids=["1", "3", "m"])
+def test_engine_outputs_equal_the_eager_dispatch(dataset, c):
+    """Host numpy inputs and the compiled row slice give the very bits the
+    eager ``jnp.asarray`` inputs and the eager ``updates[:c]`` gave."""
+    import jax
+
+    from repro.models.simple import classification_loss
+
+    eng, params, distinct, weights = _engine_round_inputs(dataset, c)
+    args = (params, distinct, weights, 0.25)
+    rest = (classification_loss, sgd(0.1))
+    got = eng.run_round(*args, np.random.default_rng(11), *rest)
+    want = _eager_dispatch(eng, *args, np.random.default_rng(11), *rest)
+
+    for a, b in zip(jax.tree_util.tree_leaves(got[0]), jax.tree_util.tree_leaves(want[0])):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert got[1].shape == want[1].shape and got[1].shape[0] == c
+    np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(want[1]))
+    np.testing.assert_array_equal(got[2], want[2])
+
+
 def test_engine_pads_heterogeneous_client_sizes():
     """Clients of different sizes stack into one padded block; padded rows
     are never drawn, so results stay finite and aggregation exact."""
